@@ -2,6 +2,7 @@
 terms recorded to experiments/hillclimb/<cell>__<variant>.json."""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"   # CPU dry run: never take the chip
 import json
 import sys
 

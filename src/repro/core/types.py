@@ -151,6 +151,20 @@ H100_SPEC = HardwareSpec(
 
 TPU_V5E_SPEC = HardwareSpec()
 
+# specs of the chips this stack runs on, keyed by ``jax.Device.device_kind``
+HARDWARE_BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E_SPEC}
+
+
+def hardware_spec_for(device_kind: str) -> HardwareSpec:
+    """The spec of a device as JAX reports it; an unknown kind is an error,
+    never a default (a planner priced on the wrong chip plans wrongly)."""
+    try:
+        return HARDWARE_BY_DEVICE_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no HardwareSpec for device kind {device_kind!r}; known: "
+            f"{sorted(HARDWARE_BY_DEVICE_KIND)}") from None
+
 
 @dataclasses.dataclass(frozen=True)
 class ClusterSpec:

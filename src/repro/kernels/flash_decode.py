@@ -62,7 +62,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     @pl.when(j == n_k - 1)
     def _finish():
         o_ref[0, 0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-                       )[0].astype(o_ref.dtype)
+                       ).astype(o_ref.dtype)
 
 
 def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, lens: jax.Array,
@@ -97,7 +97,8 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, lens: jax.Array,
             pl.BlockSpec((1, 1, block_k, D),
                          lambda b, h, j, lens: (b, h // group, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, D), lambda b, h, j, lens: (b, h, 0)),
+        out_specs=pl.BlockSpec((1, 1, 1, D),
+                               lambda b, h, j, lens: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
@@ -107,10 +108,10 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, lens: jax.Array,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, 1, D), q.dtype),
         interpret=interpret,
     )(lens.astype(jnp.int32), qt, kt, vt)
-    return out
+    return out[:, :, 0]
 
 
 def _paged_kernel(lens_ref, start_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
@@ -156,7 +157,7 @@ def _paged_kernel(lens_ref, start_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(j == jnp.minimum(last_blk, n_blocks - 1))
     def _finish():
         o_ref[0, 0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-                       )[0].astype(o_ref.dtype)
+                       ).astype(o_ref.dtype)
 
 
 def _paged_call(qt: jax.Array, kt: jax.Array, vt: jax.Array,
@@ -189,21 +190,25 @@ def _paged_call(qt: jax.Array, kt: jax.Array, vt: jax.Array,
             pl.BlockSpec((1, 1, page, D), kv_index),
             pl.BlockSpec((1, 1, page, D), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, 1, D),
-                               lambda b, h, j, lens, start, tbl: (b, h, 0)),
+        # Mosaic wants a block's last two dims equal to the array's (or
+        # 8/128-aligned): a [B, Hq, 1, D] output with (1, 1, 1, D) blocks
+        # lowers, a [B, Hq, D] one with (1, 1, D) blocks does not
+        out_specs=pl.BlockSpec((1, 1, 1, D),
+                               lambda b, h, j, lens, start, tbl: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, D), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), qt.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, 1, D), qt.dtype),
         interpret=interpret,
     )(lens.astype(jnp.int32), start.astype(jnp.int32),
       block_table.astype(jnp.int32), qt, kt, vt)
+    return out[:, :, 0]
 
 
 def flash_decode_paged_native(q: jax.Array, k_pages: jax.Array,

@@ -1,6 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import (jax locks the device
+# a CPU dry run over virtual devices: never take the chip (its subprocesses
+# would then find it held)
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any jax import (jax locks the device
 # count at first init).  Everything below is ordinary code.
 
 import argparse          # noqa: E402

@@ -421,6 +421,10 @@ class ClusterRuntime:
         self.decode_horizon = decode_horizon
         self.prefix_cache = prefix_cache
         self.decode_mode = decode_mode
+        # what the caller asked for; each engine resolves it for itself
+        # (a sharded replica's "auto" is "jnp"), the shared pool's head
+        # padding follows the unsharded resolution
+        self.attn_request = attn_impl
         self.attn_impl, _ = resolve_attn_impl(attn_impl)
         self.dtype = dtype
         self.seed = seed
@@ -521,7 +525,7 @@ class ClusterRuntime:
         common = dict(
             block_size=self.block_size, max_seqs=max_seqs, dtype=self.dtype,
             greedy=True, seed=self.seed, decode_mode=self.decode_mode,
-            attn_impl=self.attn_impl, max_blocks_per_seq=max_bps,
+            attn_impl=self.attn_request, max_blocks_per_seq=max_bps,
             prefill_chunk_tokens=self.prefill_chunk_tokens,
             decode_horizon=self.decode_horizon,
             prefix_cache=self.prefix_cache,

@@ -165,11 +165,16 @@ LOAD_STATS_KEYS = frozenset({
 })
 
 
-def resolve_attn_impl(attn_impl: str) -> tuple[str, bool]:
-    """Resolve "auto" to the backend's implementation; returns (impl, interpret)."""
+def resolve_attn_impl(attn_impl: str, sharded: bool = False
+                      ) -> tuple[str, bool]:
+    """Resolve "auto" to the backend's implementation; returns (impl, interpret).
+
+    A ``sharded`` replica (one with a mesh) resolves "auto" to "jnp": the
+    Pallas kernel is not ``shard_map``-wired yet, so GSPMD partitions the
+    gathered jnp path instead."""
     on_tpu = jax.default_backend() == "tpu"
     if attn_impl == "auto":
-        attn_impl = "kernel" if on_tpu else "jnp"
+        attn_impl = "kernel" if on_tpu and not sharded else "jnp"
     return attn_impl, attn_impl == "kernel" and not on_tpu
 
 
@@ -304,8 +309,9 @@ class ServingEngine:
             raise ValueError("decode_horizon > 1 needs decode_mode='paged'")
         self.decode_mode = decode_mode
         self.decode_horizon = decode_horizon
-        attn_impl, self._interpret = resolve_attn_impl(attn_impl)
-        self._attn_impl = attn_impl
+        attn_impl, self.interpret = resolve_attn_impl(
+            attn_impl, sharded=mesh is not None)
+        self.attn_impl = attn_impl
         self._mesh = mesh
         self._shard_plan = shard_plan
         if mesh is not None:
@@ -466,7 +472,7 @@ class ServingEngine:
         the host, once per horizon.
         """
         cfg, greedy = self.cfg, self.greedy
-        impl, interp = self._attn_impl, self._interpret
+        impl, interp = self.attn_impl, self.interpret
         trash = self.cache.trash_slot
 
         def fused(params, k, v, table_full, lens_full, ssm_full, conv_full,
