@@ -422,10 +422,8 @@ class ClusterRuntime:
         self.prefix_cache = prefix_cache
         self.decode_mode = decode_mode
         # what the caller asked for; each engine resolves it for itself
-        # (a sharded replica's "auto" is "jnp"), the shared pool's head
-        # padding follows the unsharded resolution
-        self.attn_request = attn_impl
-        self.attn_impl, _ = resolve_attn_impl(attn_impl)
+        # (a sharded replica's "auto" is "jnp")
+        self.attn_impl = attn_impl
         self.dtype = dtype
         self.seed = seed
         self.shard = shard
@@ -440,9 +438,10 @@ class ClusterRuntime:
                                 else jax.devices())
             self.pool = None    # per-replica pools, one per sub-mesh
         else:
+            # one unsharded pool: padded for the unsharded resolution
             self.pool = BlockPool(cfg, blocks_per_chip * total_chips,
                                   block_size, dtype,
-                                  head_pad_for(self.attn_impl))
+                                  head_pad_for(resolve_attn_impl(attn_impl)[0]))
         self.router: Router = router if router is not None else FlowRouter(
             [[1.0]])
         self.replicas: list[ReplicaHandle] = []
@@ -525,7 +524,7 @@ class ClusterRuntime:
         common = dict(
             block_size=self.block_size, max_seqs=max_seqs, dtype=self.dtype,
             greedy=True, seed=self.seed, decode_mode=self.decode_mode,
-            attn_impl=self.attn_request, max_blocks_per_seq=max_bps,
+            attn_impl=self.attn_impl, max_blocks_per_seq=max_bps,
             prefill_chunk_tokens=self.prefill_chunk_tokens,
             decode_horizon=self.decode_horizon,
             prefix_cache=self.prefix_cache,
