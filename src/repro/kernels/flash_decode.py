@@ -206,6 +206,7 @@ def _paged_call(qt: jax.Array, kt: jax.Array, vt: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, 1, D), qt.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(lens.astype(jnp.int32), start.astype(jnp.int32),
       block_table.astype(jnp.int32), qt, kt, vt)
     return out[:, :, 0]

@@ -1018,72 +1018,76 @@ class ClusterRuntime:
         replica whose device state disagrees with the host — and its
         requests rebuild from the request log.
         """
-        self._tick += 1
-        finished: list[EngineRequest] = []
-        pending = []
-        dispatched: set[int] = set()
-        had_work: dict[int, bool] = {}
-        for h in self.replicas:
-            if h.dead:
-                continue
-            eng = h.engine
-            busy = len(eng.active)
-            h.slot_ticks += busy          # expected: ~1 token / slot / tick
-            work = bool(eng.active or (eng.waiting and eng.admitting))
-            had_work[h.index] = work
-            if not work:
-                continue
-            h.work_ticks += 1
-            if h.period > 1 and self._tick % h.period:
-                continue                  # injected straggler skips this tick
-            if (self.faults is not None
-                    and self.faults.stalled(self._tick, h.index)):
-                continue                  # injected stall: frozen, no error
-            if self._tick < h.backoff_until:
-                # backing off after a failure: intentional non-progress, so
-                # the watchdog must not count it
-                had_work[h.index] = False
-                continue
-            try:
-                if self.faults is not None:
-                    spec = self.faults.dispatch_fault(self._tick, h.index)
-                    if spec is not None:
-                        raise error_for(spec)
-                pend = eng.step_async()
-            except ReplicaCrash as e:
-                self._fail(h, e, trust_pages=not e.lose_pages)
-                continue
-            except (FaultError, MemoryError) as e:
-                self._transient(h, e)
-                continue
-            h.failures = 0
-            h.progress_ticks += 1
-            dispatched.add(h.index)
-            pending.append((h, eng.tokens_out, pend))
-        if self.rebalance is not None:
-            # the async overlap window: every dispatch is in flight, no
-            # sync has read anything back.  Draining a zero-progress
-            # replica here is safe — it has no pending dispatch to race
-            # with, and imports land in destination slots outside any
-            # pending decode's captured batch.
-            self._moves_left = self.rebalance.max_moves_per_tick
-            self._watchdog(dispatched, had_work)
-        for h, t0, pend in pending:
-            try:
-                done = h.engine.finish_step(pend)
-            except (FaultError, MemoryError) as e:
-                self._fail(h, e, trust_pages=False)
-                continue
-            for r in done:
-                self._record_finish(r, owner=h)
-                finished.append(r)
-            h.emitted_span += h.engine.tokens_out - t0
-            self._sync_log(h.engine)
-        self._handoff_post()
-        if self.rebalance is not None:
-            self._rebalance_post()
-        self._drain_prefix_events()
-        return finished
+        tm = self.telemetry
+        with tm.span("cluster.step"):
+            self._tick += 1
+            finished: list[EngineRequest] = []
+            pending = []
+            dispatched: set[int] = set()
+            had_work: dict[int, bool] = {}
+            for h in self.replicas:
+                if h.dead:
+                    continue
+                eng = h.engine
+                busy = len(eng.active)
+                h.slot_ticks += busy      # expected: ~1 token / slot / tick
+                work = bool(eng.active or (eng.waiting and eng.admitting))
+                had_work[h.index] = work
+                if not work:
+                    continue
+                h.work_ticks += 1
+                if h.period > 1 and self._tick % h.period:
+                    continue              # injected straggler skips this tick
+                if (self.faults is not None
+                        and self.faults.stalled(self._tick, h.index)):
+                    continue              # injected stall: frozen, no error
+                if self._tick < h.backoff_until:
+                    # backing off after a failure: intentional non-progress, so
+                    # the watchdog must not count it
+                    had_work[h.index] = False
+                    continue
+                try:
+                    if self.faults is not None:
+                        spec = self.faults.dispatch_fault(self._tick, h.index)
+                        if spec is not None:
+                            raise error_for(spec)
+                    pend = eng.step_async()
+                except ReplicaCrash as e:
+                    self._fail(h, e, trust_pages=not e.lose_pages)
+                    continue
+                except (FaultError, MemoryError) as e:
+                    self._transient(h, e)
+                    continue
+                h.failures = 0
+                h.progress_ticks += 1
+                dispatched.add(h.index)
+                pending.append((h, eng.tokens_out, pend))
+            if self.rebalance is not None:
+                # the async overlap window: every dispatch is in flight, no
+                # sync has read anything back.  Draining a zero-progress
+                # replica here is safe — it has no pending dispatch to race
+                # with, and imports land in destination slots outside any
+                # pending decode's captured batch.
+                self._moves_left = self.rebalance.max_moves_per_tick
+                self._watchdog(dispatched, had_work)
+            for h, t0, pend in pending:
+                try:
+                    done = h.engine.finish_step(pend)
+                except (FaultError, MemoryError) as e:
+                    self._fail(h, e, trust_pages=False)
+                    continue
+                with tm.span("cluster.sync_log"):
+                    for r in done:
+                        self._record_finish(r, owner=h)
+                        finished.append(r)
+                    h.emitted_span += h.engine.tokens_out - t0
+                    self._sync_log(h.engine)
+            with tm.span("cluster.post"):
+                self._handoff_post()
+                if self.rebalance is not None:
+                    self._rebalance_post()
+                self._drain_prefix_events()
+            return finished
 
     def _handoff_post(self) -> None:
         """Disaggregated prefill→decode handoff, run post-sync each tick.

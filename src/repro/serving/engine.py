@@ -142,8 +142,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.models import (DecodeCache, PagedDecodeState, decode_loop_paged,
-                          decode_step, prefill, prefill_chunk)
+from repro import models
+from repro.models import DecodeCache, PagedDecodeState, decode_loop_paged
 from repro.models.config import ModelConfig
 from repro.models.sampling import sample
 from repro.pshard import sharding_rules
@@ -426,17 +426,27 @@ class ServingEngine:
         # drains these into per-workload-type hit rates for the planner
         self.prefix_events: list[tuple[int, int, int]] = []
 
-        self._prefill = jax.jit(
-            lambda p, toks: prefill(p, cfg, tokens=toks))
-        self._decode = jax.jit(
-            lambda p, toks, cache: decode_step(p, cfg, toks, cache))
-        self._fused = self._build_fused()
         trash = self.cache.num_blocks
+
+        # named functions, so each program is ``jit_<name>`` in a trace
+        def prefill(p, toks):
+            return models.prefill(p, cfg, tokens=toks)
+
+        def prefill_chunk(p, t, k, v, tab, s, nv):
+            return models.prefill_chunk(p, cfg, t, k, v, tab, s, nv, trash)
+
+        def decode_dense(p, toks, cache):
+            return models.decode_step(p, cfg, toks, cache)
+
+        self._prefill = jax.jit(prefill)
+        self._decode = jax.jit(decode_dense)
+        self._fused = self._build_fused()
         donate = (2, 3) if jax.default_backend() != "cpu" else ()
-        self._chunk = jax.jit(
-            lambda p, t, k, v, tab, s, nv: prefill_chunk(
-                p, cfg, t, k, v, tab, s, nv, trash),
-            donate_argnums=donate)
+        self._chunk = jax.jit(prefill_chunk, donate_argnums=donate)
+
+    def _span(self, name: str):
+        """A host span of this replica (``serving.telemetry``'s names)."""
+        return self.telemetry.span(name, replica=self.trace_id)
 
     def _rules_ctx(self):
         """Context installing the replica's logical-axis sharding rules.
@@ -1100,9 +1110,12 @@ class ServingEngine:
 
     def _pick(self, logits: jax.Array) -> np.ndarray:
         if self.greedy:
-            return np.asarray(sample(logits, self.cfg, self.key))
-        self.key, sub = jax.random.split(self.key)
-        return np.asarray(sample(logits, self.cfg, sub, temperature=1.0))
+            ids = sample(logits, self.cfg, self.key)
+        else:
+            self.key, sub = jax.random.split(self.key)
+            ids = sample(logits, self.cfg, sub, temperature=1.0)
+        with self._span("engine.wait_device"):
+            return np.asarray(ids)
 
     # -- decode paths ----------------------------------------------------------
 
@@ -1140,44 +1153,48 @@ class ServingEngine:
         mirror advances inside the loop.
         """
         slots = sorted(slots)
-        updates = []                     # page capacity for the whole horizon
-        for s in slots:
-            upd = self.cache.extend_for(s, horizon, sync_device=False)
-            if upd is not None:
-                updates.append(upd)
-        self.cache.apply_table_updates(updates)   # one scatter for the batch
-        B = len(slots)
-        bucket = _pow2_bucket(B, self.max_seqs)
-        trash = self.cache.trash_slot
-        pad = bucket - B
-        slot_arr = np.array(slots + [trash] * pad, np.int32)
-        last = np.array([self.active[s].generated[-1] for s in slots]
-                        + [0] * pad, np.int32)
-        bs = self.cache.block_size
-        need = (int(self.cache.seq_lens[slots].max()) + bs - 1) // bs
-        n_pages = _pow2_bucket(need, self.cache.max_blocks_per_seq)
-        step0 = self._sample_step
-        self._sample_step += horizon
-        self.horizon_counts[horizon] = self.horizon_counts.get(horizon, 0) + 1
-        self.last_horizon = horizon
-        if self.telemetry.enabled:
-            self.telemetry.emit("dispatch", replica=self.trace_id,
-                                n=B, h=horizon)
-        with self._rules_ctx():
-            toks, k, v, lens_dev, ssm, conv = self._fused(
-                self.params, self.cache.k, self.cache.v,
-                self.cache.block_table_dev, self.cache.seq_lens_dev,
-                self.cache.ssm, self.cache.conv,
-                jnp.asarray(slot_arr), jnp.asarray(last), self.key,
-                jnp.int32(step0), n_pages=n_pages, horizon=horizon)
-        self.cache.k, self.cache.v = k, v
-        self.cache.seq_lens_dev = lens_dev
-        self.cache.ssm, self.cache.conv = ssm, conv
-        return PendingDecode(slots, toks, horizon)
+        with self._span("engine.table_update"):
+            updates = []                 # page capacity for the whole horizon
+            for s in slots:
+                upd = self.cache.extend_for(s, horizon, sync_device=False)
+                if upd is not None:
+                    updates.append(upd)
+            self.cache.apply_table_updates(updates)   # one scatter, the batch
+        with self._span("engine.decode_dispatch"):
+            B = len(slots)
+            bucket = _pow2_bucket(B, self.max_seqs)
+            trash = self.cache.trash_slot
+            pad = bucket - B
+            slot_arr = np.array(slots + [trash] * pad, np.int32)
+            last = np.array([self.active[s].generated[-1] for s in slots]
+                            + [0] * pad, np.int32)
+            bs = self.cache.block_size
+            need = (int(self.cache.seq_lens[slots].max()) + bs - 1) // bs
+            n_pages = _pow2_bucket(need, self.cache.max_blocks_per_seq)
+            step0 = self._sample_step
+            self._sample_step += horizon
+            self.horizon_counts[horizon] = (
+                self.horizon_counts.get(horizon, 0) + 1)
+            self.last_horizon = horizon
+            if self.telemetry.enabled:
+                self.telemetry.emit("dispatch", replica=self.trace_id,
+                                    n=B, h=horizon)
+            with self._rules_ctx():
+                toks, k, v, lens_dev, ssm, conv = self._fused(
+                    self.params, self.cache.k, self.cache.v,
+                    self.cache.block_table_dev, self.cache.seq_lens_dev,
+                    self.cache.ssm, self.cache.conv,
+                    jnp.asarray(slot_arr), jnp.asarray(last), self.key,
+                    jnp.int32(step0), n_pages=n_pages, horizon=horizon)
+            self.cache.k, self.cache.v = k, v
+            self.cache.seq_lens_dev = lens_dev
+            self.cache.ssm, self.cache.conv = ssm, conv
+            return PendingDecode(slots, toks, horizon)
 
     def _finish_decode(self, pending: PendingDecode) -> None:
         """Sync a dispatched horizon: ONE [B, H] device→host transfer."""
-        toks = np.asarray(pending.tokens)
+        with self._span("engine.wait_device"):
+            toks = np.asarray(pending.tokens)
         self.decode_syncs += 1
         for i, s in enumerate(pending.slots):
             r = self.active[s]
@@ -1270,7 +1287,8 @@ class ServingEngine:
         """
         self.steps += 1
         decode_slots = [s for s, r in self.active.items() if not r.prefilling]
-        admitted = self._admit()
+        with self._span("engine.admit"):
+            admitted = self._admit()
         chunk = self.prefill_chunk_tokens
         # prefix-cache hits (prefill_pos > 0) must not re-run the full
         # prompt: they resume mid-prompt via the chunk forward instead
@@ -1278,26 +1296,30 @@ class ServingEngine:
                    if (chunk is None or len(r.prefill_tokens) <= chunk)
                    and r.prefill_pos == 0]
         if oneshot:
-            self._run_prefill(oneshot)
+            with self._span("engine.prefill"):
+                self._run_prefill(oneshot)
         if chunk is None:
             resumed = [r for r in admitted
                        if 0 < r.prefill_pos < len(r.prefill_tokens)]
             if resumed:
-                self._resume_prefill(resumed)
+                with self._span("engine.prefill"):
+                    self._resume_prefill(resumed)
         # chunked engines resume cached admissions in _advance_chunked,
         # which already starts each chunk at prefill_pos
         # capture the chunk event BEFORE advancing: a prefill that completes
         # this very step is still a per-step event (its sequence must join
         # the decode batch next step, not a horizon later)
         chunking = any(r.prefilling for r in self.active.values())
-        if chunk is not None:
-            self._advance_chunked()      # longer admissions, chunk by chunk
+        if chunk is not None and chunking:
+            with self._span("engine.prefill"):
+                self._advance_chunked()  # longer admissions, chunk by chunk
         if decode_slots:
             if self.decode_mode == "paged":
                 h = self._safe_horizon(decode_slots,
                                        bool(admitted) or chunking)
                 return self._dispatch_decode(decode_slots, h)
-            self._run_decode_dense(decode_slots)
+            with self._span("engine.decode_dispatch"):
+                self._run_decode_dense(decode_slots)
         return None
 
     def _shed_slow(self) -> None:
@@ -1330,10 +1352,11 @@ class ServingEngine:
                     ) -> list[EngineRequest]:
         """Sync a dispatched step (one device→host token transfer), retire
         finished requests, and shed TPOT-blown ones."""
-        if pending is not None:
-            self._finish_decode(pending)
-        done = self._retire()
-        self._shed_slow()
+        with self._span("engine.finish"):
+            if pending is not None:
+                self._finish_decode(pending)
+            done = self._retire()
+            self._shed_slow()
         return done
 
     def step(self) -> list[EngineRequest]:

@@ -13,7 +13,7 @@ through the serving stack (`engine.py`, `cluster.py`, `migration.py`,
   dispatch/sync boundaries (never inside jitted code), carry timestamps
   from an injectable monotonic clock (deterministic in tests), and the
   whole path is a true no-op when disabled.
-* :class:`Metrics` — a registry of counters, gauges, and log-bucketed
+* :class:`Metrics` — a registry of counters and log-bucketed
   histograms (TTFT / TPOT / queue delay / switch stall / recovery
   stall) cheap enough to stay on in production.
 * :class:`DecisionAudit` — one record per ``Orchestrator.plan_span``
@@ -58,6 +58,30 @@ switch_commit           phase ("begin"|"end"), span
 switch_rollback         phase ("begin"|"end"), span
 ======================  ======================================================
 
+Host spans (:meth:`Telemetry.span`) are a separate channel: on an enabled
+bundle each is a ``jax.profiler.TraceAnnotation``, so it lands in the
+profiler's trace on the same clock as the device's programs, and never in
+the ring buffer or the Chrome export.  Their names and nesting:
+
+========================  ==================================================
+span                      covers
+========================  ==================================================
+cluster.step              all of ``ClusterRuntime.step``
+  engine.admit            ``_admit`` (SLO shedding, prefix attach)
+  engine.prefill          one-shot, resumed or chunked prefill, with its
+                          first-token read and page writes
+  engine.table_update     the batch's page extends and block-table scatter
+  engine.decode_dispatch  enqueueing the fused decode (or the dense decode)
+  engine.finish           ``finish_step``: token bookkeeping, retire, shed
+    engine.wait_device    a host read of a device result (inside
+                          ``engine.finish`` or ``engine.prefill``)
+  cluster.sync_log        a replica's finished records and request-log sync
+  cluster.post            handoffs, rebalancing, prefix-event drain
+gc                        a collection by Python's garbage collector
+========================  ==================================================
+
+Engine spans carry ``replica=<trace_id>``.
+
 Every submitted request's stream ends in exactly one *terminal* event
 (retire / shed / finish_log) — even across crashes and repeated
 migrations; ``tests/test_telemetry.py`` enforces this under chaos.
@@ -72,10 +96,13 @@ migrations.  :func:`validate_chrome_trace` is the CI-side schema check
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import gc
 import json
 import math
 import time
+import weakref
 from collections import deque
 
 TERMINAL_KINDS = frozenset({"retire", "shed", "finish_log"})
@@ -190,7 +217,7 @@ class Histogram:
 
 
 class Metrics:
-    """Registry of counters, gauges, and histograms.
+    """Registry of counters and histograms.
 
     All mutators are no-ops when disabled; readers always work (they
     just see empty state).
@@ -199,18 +226,12 @@ class Metrics:
     def __init__(self, enabled: bool = True):
         self.enabled = bool(enabled)
         self.counters: dict[str, float] = {}
-        self.gauges: dict[str, float] = {}
         self.histograms: dict[str, Histogram] = {}
 
     def count(self, name: str, inc: float = 1.0) -> None:
         if not self.enabled:
             return
         self.counters[name] = self.counters.get(name, 0.0) + inc
-
-    def gauge(self, name: str, value: float) -> None:
-        if not self.enabled:
-            return
-        self.gauges[name] = float(value)
 
     def observe(self, name: str, value: float) -> None:
         if not self.enabled:
@@ -328,6 +349,49 @@ class DecisionAudit:
         return sum(errs) / len(errs) if errs else math.nan
 
 
+# what ``Telemetry.span`` returns on a disabled bundle: one shared object,
+# so a span costs the ``enabled`` test and nothing else
+_NULL_SPAN = contextlib.nullcontext()
+
+
+class _GcSpan:
+    """A ``gc`` span around each collection, through ``gc.callbacks``,
+    while at least one enabled :class:`Telemetry` is alive."""
+
+    def __init__(self):
+        self.live = 0          # enabled bundles not yet collected
+        self._open = None      # the annotation of a collection in progress
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            from jax.profiler import TraceAnnotation
+            self._open = TraceAnnotation("gc")
+            self._open.__enter__()
+        else:
+            self._close()
+
+    def _close(self) -> None:
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+    def hold(self, owner) -> None:
+        """Keep the hook registered for as long as ``owner`` lives."""
+        if self.live == 0:
+            gc.callbacks.append(self)
+        self.live += 1
+        weakref.finalize(owner, self._release)
+
+    def _release(self) -> None:
+        self.live -= 1
+        if self.live == 0:
+            gc.callbacks.remove(self)
+            self._close()      # released mid-collection: no "stop" comes
+
+
+_GC_SPAN = _GcSpan()
+
+
 class Telemetry:
     """The bundle the serving stack passes around.
 
@@ -346,6 +410,18 @@ class Telemetry:
                              enabled=enabled)
         self.metrics = Metrics(enabled=enabled)
         self.audit = DecisionAudit(enabled=enabled)
+        if self.enabled:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+            _GC_SPAN.hold(self)
+
+    def span(self, name: str, **meta):
+        """A host span named ``name`` in the profiler's trace (see the
+        module docstring for the names); on a disabled bundle, a shared
+        no-op context.  ``meta`` becomes the span's arguments."""
+        if not self.enabled:
+            return _NULL_SPAN
+        return self._annotation(name, **meta)
 
     def emit(self, kind: str, rid: int = -1, replica: int = -1,
              **data) -> None:

@@ -10,6 +10,7 @@ fault plan (replica crash + a switch that fails mid-migration) every
 submitted request's event stream still ends in exactly one terminal event
 and every migration pairs a source with a destination replica.
 """
+import gc
 import json
 
 import jax
@@ -26,10 +27,11 @@ from repro.models import init_params
 from repro.serving.cluster import ClusterRuntime
 from repro.serving.engine import LOAD_STATS_KEYS, ServingEngine
 from repro.serving.faults import FaultPlan
+from repro.serving import telemetry as telemetry_mod
 from repro.serving.router import FlowRouter
-from repro.serving.telemetry import (ORCH_TID, TERMINAL_KINDS, DecisionAudit,
-                                     Histogram, Telemetry, Tracer,
-                                     export_chrome_trace,
+from repro.serving.telemetry import (NULL_TELEMETRY, ORCH_TID, TERMINAL_KINDS,
+                                     DecisionAudit, Histogram, Telemetry,
+                                     Tracer, export_chrome_trace,
                                      validate_chrome_trace)
 
 ARCH = [WorkloadType(1275, 287), WorkloadType(139, 133),
@@ -114,6 +116,117 @@ def test_validator_rejects_malformed_traces():
         {"ph": "X", "name": "r", "pid": 0, "tid": 1, "ts": 0, "dur": -1}]}
     with pytest.raises(ValueError, match="dur"):
         validate_chrome_trace(bad_dur)
+
+
+# ---------------------------------------------------------------------------
+# Host spans on the profiler's clock: no-op when disabled, TraceAnnotation
+# when enabled, the gc hook's lifetime, and their nesting in a cluster tick.
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture()
+def annotations(monkeypatch):
+    """``jax.profiler.TraceAnnotation`` replaced by a recorder; returns the
+    log of ``(enter|exit, name, meta)``."""
+    log = []
+
+    class Recorder:
+        def __init__(self, name, **meta):
+            self.name, self.meta = name, meta
+
+        def __enter__(self):
+            log.append(("enter", self.name, self.meta))
+            return self
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name, self.meta))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    return log
+
+
+def test_disabled_span_is_one_shared_no_op(monkeypatch):
+    class Refuse:
+        def __init__(self, *a, **kw):
+            raise AssertionError("a disabled bundle built a TraceAnnotation")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Refuse)
+    off = Telemetry(enabled=False)
+    span = off.span("cluster.step")
+    assert span is off.span("engine.admit", replica=3)
+    assert span is NULL_TELEMETRY.span("engine.finish", replica=0)
+    with span:
+        with NULL_TELEMETRY.span("engine.wait_device"):
+            pass
+
+
+def test_enabled_span_enters_a_trace_annotation(annotations):
+    tm = Telemetry(clock=FakeClock())
+    with tm.span("engine.admit", replica=2):
+        with tm.span("engine.wait_device"):
+            pass
+    assert [x for x in annotations if x[1] != "gc"] == [
+        ("enter", "engine.admit", {"replica": 2}),
+        ("enter", "engine.wait_device", {}),
+        ("exit", "engine.wait_device", {}),
+        ("exit", "engine.admit", {"replica": 2})]
+    assert not tm.tracer.events          # spans stay out of the ring
+
+
+def test_gc_span_hook_lives_with_the_enabled_bundles(annotations):
+    hook = telemetry_mod._GC_SPAN
+    gc.collect()                         # release bundles other tests left
+    live = hook.live
+    Telemetry(enabled=False)
+    assert hook.live == live
+    tm = Telemetry()
+    assert hook.live == live + 1 and hook in gc.callbacks
+    annotations.clear()
+    gc.collect()
+    assert annotations[:1] == [("enter", "gc", {})]
+    assert annotations[-1:] == [("exit", "gc", {})]
+    del tm
+    gc.collect()
+    assert hook.live == live
+    assert (hook in gc.callbacks) == (live > 0)
+
+
+def test_cluster_tick_spans_nest(cfg_params, annotations):
+    cfg, params = cfg_params
+    rt = ClusterRuntime(cfg, params, total_chips=1, blocks_per_chip=32,
+                        seqs_per_chip=2, block_size=8,
+                        telemetry=Telemetry(clock=FakeClock()))
+
+    class _Plan:
+        deployment = type("D", (), {"replicas": [ReplicaConfig(1)]})()
+        fractions = [[1.0]]
+
+    rt.apply_plan(_Plan())
+    rng = np.random.RandomState(5)
+    for rid in range(3):
+        rt.submit(rid, rng.randint(0, cfg.vocab_size, 8).astype(np.int32), 3)
+    annotations.clear()
+    rt.run_until_idle()
+    assert len(rt.results) == 3
+    stack, seen = [], set()
+    for kind, name, meta in annotations:
+        if name == "gc":
+            continue
+        if kind == "enter":
+            seen.add((name, stack[-1] if stack else None))
+            assert meta == ({"replica": 0} if name.startswith("engine.")
+                            else {})
+            stack.append(name)
+        else:
+            assert stack.pop() == name
+    assert not stack
+    top = {"engine.admit", "engine.prefill", "engine.table_update",
+           "engine.decode_dispatch", "engine.finish", "cluster.sync_log",
+           "cluster.post"}
+    assert seen == ({("cluster.step", None)}
+                    | {(n, "cluster.step") for n in top}
+                    | {("engine.wait_device", "engine.prefill"),
+                       ("engine.wait_device", "engine.finish")})
 
 
 # ---------------------------------------------------------------------------
