@@ -3,19 +3,29 @@
 Variants:
   * ``flash_decode``             — dense cache [B, S, Hkv, D], grid (B, Hq, n_k)
     with online-softmax scratch accumulation and per-sequence length masking.
-  * ``flash_decode_paged``       — vLLM-style paged cache: the block table rides
-    in scalar-prefetch SMEM (PrefetchScalarGridSpec) and the K/V index maps
-    dereference it, so pages are fetched HBM->VMEM exactly once, in table
-    order.  This is the TPU-native form of the serving engine's decode path.
+  * ``flash_decode_paged``       — vLLM-style paged cache, the TPU-native form
+    of the serving engine's decode path (below).
   * ``flash_decode_paged_batch`` — multi-layer entry point over pools already
     stored in kernel-native layout [L, P, Hkv, page, D]: the engine issues one
     pallas_call per layer with no per-(layer, step) transposes or reshapes.
 
+The paged kernel's grid is (B, Hkv, n_blocks).  A grid step takes all the
+query heads of one KV head's group as one [group, D] block and one block of
+``pages_per_block`` pages of that head, so each K/V page is read once per KV
+head and scores are a [group, block tokens] matmul.  Pages per block is the
+largest power of two with pages x page <= 256 tokens that the table width
+holds and divides (``_pages_per_block``); the engine's page buckets are
+powers of two.  The pools stay in HBM (``pl.ANY``); the block table rides in
+scalar-prefetch SMEM, and each block's pages are copied into a
+double-buffered VMEM block, one DMA per page, while the previous block
+computes: each live step starts the copies of the next live step in grid
+order.
+
 Per-sequence masking is a [start, len) window: ``lens`` masks the invalid
 tail, ``start`` (optional) masks the head for local/sliding-window layers.
-Blocks entirely outside the window are skipped (``pl.when`` early exit) and
-their K/V index maps are clamped into the live range so no extra pages are
-DMA'd.  Softcap supports gemma2.
+A block wholly outside the window starts no copy and no matmul; in a block
+that straddles its edge, pages outside it are not copied either.  Softcap
+supports gemma2.
 """
 from __future__ import annotations
 
@@ -114,10 +124,65 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, lens: jax.Array,
     return out[:, :, 0]
 
 
-def _paged_kernel(lens_ref, start_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, scale, block, n_blocks, softcap):
-    b = pl.program_id(0)
-    j = pl.program_id(2)
+# Tokens one grid step of the paged kernel covers at most: enough that a
+# step's fixed cost is small beside its copies, few enough that two K and
+# two V blocks stay small in VMEM.
+BLOCK_TOKENS = 256
+
+
+def _pages_per_block(page: int, max_pages: int) -> int:
+    """Pages one grid step covers: the largest power of two whose pages hold
+    at most ``BLOCK_TOKENS`` tokens, no more than the table holds, and that
+    divides the table width.  The engine's page buckets are powers of two,
+    so the last condition only bites on odd tables from direct callers."""
+    ppb = 1
+    while (2 * ppb * page <= BLOCK_TOKENS and 2 * ppb <= max_pages
+           and max_pages % (2 * ppb) == 0):
+        ppb *= 2
+    return ppb
+
+
+def _paged_kernel(lens_ref, start_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, k_sem, v_sem, flight, m_scr, l_scr, acc_scr,
+                  *, scale, softcap, ppb, n_blocks):
+    b, h, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    n_b, n_h = pl.num_programs(0), pl.num_programs(1)
+    page = k_hbm.shape[2]
+    bk = ppb * page
+
+    def live_pages(bb):
+        """Pages [first, last] of sequence ``bb`` that hold [start, lens);
+        last < first when the window is empty."""
+        length, start = lens_ref[bb], start_ref[bb]
+        last = jnp.where(start < length,
+                         (jnp.maximum(length, 1) - 1) // page, -1)
+        return start // page, jnp.minimum(last, n_blocks * ppb - 1)
+
+    def block_copies(bb, hh, jj, slot, wait):
+        """Start (or wait for) the HBM->VMEM copies of block ``jj`` of
+        sequence ``bb``, KV head ``hh``: one per live page, into ``slot``."""
+        first, last = live_pages(bb)
+        for i in range(ppb):
+            p = jj * ppb + i
+
+            @pl.when((p >= first) & (p <= last))
+            def _copy():
+                for pool, buf, sem in ((k_hbm, k_buf, k_sem),
+                                       (v_hbm, v_buf, v_sem)):
+                    cp = pltpu.make_async_copy(
+                        pool.at[table_ref[bb, p], hh],
+                        buf.at[slot, pl.ds(i * page, page)], sem.at[slot])
+                    if wait:
+                        cp.wait()
+                    else:
+                        cp.start()
+
+    # flight[0]: the slot holding the current block; flight[1]: 1 when the
+    # previous live step already started this block's copies
+    @pl.when((b == 0) & (h == 0) & (j == 0))
+    def _first_step():
+        flight[0] = 0
+        flight[1] = 0
 
     @pl.when(j == 0)
     def _init():
@@ -125,25 +190,54 @@ def _paged_kernel(lens_ref, start_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    length = lens_ref[b]
-    start = start_ref[b]
-    first_blk = start // block
-    last_blk = jnp.maximum(length - 1, 0) // block
+    first, last = live_pages(b)
 
-    # early exit: blocks fully outside [start, length) contribute nothing;
-    # their index maps are clamped into the live range so they also move no
-    # new data HBM->VMEM (same block index as the previous grid step).
-    @pl.when((j >= first_blk) & (j <= last_blk) & (length > 0))
+    # dead blocks (wholly outside [start, lens)) start no copy and no matmul
+    @pl.when((j * ppb <= last) & (j * ppb + ppb - 1 >= first))
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)            # [1, D]
-        k = k_ref[0, 0].astype(jnp.float32)            # [block, D]
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        slot = flight[0]
+
+        @pl.when(flight[1] == 0)
+        def _start_own():
+            block_copies(b, h, j, slot, wait=False)
+
+        # the next live step in grid order: the next block of this head, the
+        # first block of the next head, or that of the next sequence
+        b1 = jnp.minimum(b + 1, n_b - 1)
+        first1, last1 = live_pages(b1)
+        more_blocks = (j + 1) * ppb <= last
+        more_heads = h + 1 < n_h
+        next_seq = (b + 1 < n_b) & (first1 <= last1)
+        nb = jnp.where(more_blocks | more_heads, b, b1)
+        nh = jnp.where(more_blocks, h, jnp.where(more_heads, h + 1, 0))
+        nj = jnp.where(more_blocks, j + 1,
+                       jnp.where(more_heads, first // ppb, first1 // ppb))
+        has_next = more_blocks | more_heads | next_seq
+
+        @pl.when(has_next)
+        def _prefetch():
+            block_copies(nb, nh, nj, 1 - slot, wait=False)
+
+        flight[0] = jnp.where(has_next, 1 - slot, slot)
+        flight[1] = has_next.astype(jnp.int32)
+        block_copies(b, h, j, slot, wait=True)
+
+        q = q_ref[0, 0]                                  # [group, D]
+        k = k_buf[slot]                                  # [bk, D]
+        dt = jnp.promote_types(q.dtype, k.dtype)
+        s = jax.lax.dot_general(q.astype(dt), k.astype(dt),
+                                (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if softcap > 0.0:
             s = softcap * jnp.tanh(s / softcap)
-        pos = j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        length, start = lens_ref[b], start_ref[b]
+        pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where((pos >= start) & (pos < length), s, NEG_INF)
+        # pages outside the window were not copied: their rows hold stale
+        # VMEM, which must not reach P.V even at zero weight
+        row = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+        v = jnp.where((row >= start) & (row < length),
+                      v_buf[slot].astype(jnp.float32), 0.0)
 
         m_prev = m_scr[...]
         m_cur = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
@@ -154,62 +248,65 @@ def _paged_kernel(lens_ref, start_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_scr[...] = m_cur
 
-    @pl.when(j == jnp.minimum(last_blk, n_blocks - 1))
+    @pl.when(j == n_blocks - 1)
     def _finish():
         o_ref[0, 0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
                        ).astype(o_ref.dtype)
 
 
-def _paged_call(qt: jax.Array, kt: jax.Array, vt: jax.Array,
+def _paged_call(q: jax.Array, kt: jax.Array, vt: jax.Array,
                 block_table: jax.Array, lens: jax.Array, start: jax.Array,
                 *, softcap: float, scale: float, interpret: bool) -> jax.Array:
     """Core pallas_call over kernel-native layouts.
 
-    qt: [B, Hq, 1, D]; kt/vt: [P, Hkv, page, D]; block_table: [B, max_pages];
+    q: [B, Hq, D]; kt/vt: [P, Hkv, page, D]; block_table: [B, max_pages];
     lens/start: [B].  Returns [B, Hq, D].
     """
-    B, Hq, _, D = qt.shape
+    B, Hq, D = q.shape
     _, Hkv, page, _ = kt.shape
     group = Hq // Hkv
     max_pages = block_table.shape[1]
+    ppb = _pages_per_block(page, max_pages)
+    n_blocks = max_pages // ppb
+    bk = ppb * page
 
-    def kv_index(b, h, j, lens, start, tbl):
-        first = start[b] // page
-        last = jnp.maximum(lens[b] - 1, 0) // page
-        jj = jnp.clip(j, first, last)
-        return (tbl[b, jj], h // group, 0, 0)
-
-    kernel = functools.partial(_paged_kernel, scale=scale, block=page,
-                               n_blocks=max_pages, softcap=softcap)
+    kernel = functools.partial(_paged_kernel, scale=scale, softcap=softcap,
+                               ppb=ppb, n_blocks=n_blocks)
+    # the group's query heads as one block whose last two dims are the
+    # array's, which Mosaic takes for any group size
+    q_spec = pl.BlockSpec((1, 1, group, D),
+                          lambda b, h, j, lens, start, tbl: (b, h, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,                     # lens, start, block_table
-        grid=(B, Hq, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, D),
-                         lambda b, h, j, lens, start, tbl: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, page, D), kv_index),
-            pl.BlockSpec((1, 1, page, D), kv_index),
-        ],
-        # Mosaic wants a block's last two dims equal to the array's (or
-        # 8/128-aligned): a [B, Hq, 1, D] output with (1, 1, 1, D) blocks
-        # lowers, a [B, Hq, D] one with (1, 1, D) blocks does not
-        out_specs=pl.BlockSpec((1, 1, 1, D),
-                               lambda b, h, j, lens, start, tbl: (b, h, 0, 0)),
+        grid=(B, Hkv, n_blocks),
+        in_specs=[q_spec,
+                  pl.BlockSpec(memory_space=pl.ANY),   # pools stay in HBM
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, D), jnp.float32),
+            pltpu.VMEM((2, bk, D), kt.dtype),      # double-buffered blocks
+            pltpu.VMEM((2, bk, D), vt.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((2,), jnp.int32),
+            pltpu.VMEM((group, 1), jnp.float32),
+            pltpu.VMEM((group, 1), jnp.float32),
+            pltpu.VMEM((group, D), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, 1, D), qt.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
+        # a block's copies are started by the live step before it, so the
+        # grid runs in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
         name="paged_decode_attention",
     )(lens.astype(jnp.int32), start.astype(jnp.int32),
-      block_table.astype(jnp.int32), qt, kt, vt)
-    return out[:, :, 0]
+      block_table.astype(jnp.int32), q.reshape(B, Hkv, group, D), kt, vt)
+    return out.reshape(B, Hq, D)
 
 
 def flash_decode_paged_native(q: jax.Array, k_pages: jax.Array,
@@ -230,8 +327,8 @@ def flash_decode_paged_native(q: jax.Array, k_pages: jax.Array,
         scale = 1.0 / (D ** 0.5)
     if start is None:
         start = jnp.zeros_like(lens)
-    return _paged_call(q[:, :, None, :], k_pages, v_pages, block_table, lens,
-                       start, softcap=softcap, scale=scale,
+    return _paged_call(q, k_pages, v_pages, block_table, lens, start,
+                       softcap=softcap, scale=scale,
                        interpret=interpret)
 
 
@@ -249,15 +346,14 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         (local/sliding-window attention), defaults to 0.
     Returns [B, Hq, D].
     """
-    B, Hq, D = q.shape
+    D = q.shape[-1]
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     if start is None:
         start = jnp.zeros_like(lens)
-    qt = q[:, :, None, :]
     kt = jnp.swapaxes(k_pages, 1, 2)               # [pages, Hkv, page, D]
     vt = jnp.swapaxes(v_pages, 1, 2)
-    return _paged_call(qt, kt, vt, block_table, lens, start,
+    return _paged_call(q, kt, vt, block_table, lens, start,
                        softcap=softcap, scale=scale, interpret=interpret)
 
 
@@ -284,7 +380,7 @@ def flash_decode_paged_batch(q: jax.Array, k_pages: jax.Array,
 
     def one_layer(args):
         ql, kl, vl = args
-        return _paged_call(ql[:, :, None, :], kl, vl, block_table, lens,
+        return _paged_call(ql, kl, vl, block_table, lens,
                            start, softcap=softcap, scale=scale,
                            interpret=interpret)
 

@@ -44,7 +44,7 @@ def _gather(k_pages, tbl):
     return k.reshape(B, n * p, H, D)
 
 
-@pytest.mark.parametrize("group", [1, 4, 8])
+@pytest.mark.parametrize("group", [1, 4, 8, 2, 12])
 @pytest.mark.parametrize("softcap", [0.0, 30.0])
 def test_paged_vs_dense_vs_ref(group, softcap):
     q, kp, vp, tbl, lens = _paged_case(group=group)
@@ -69,6 +69,53 @@ def test_paged_start_window_masks_head():
     r = ref.flash_decode_paged_ref(q, kp, vp, tbl, lens, start=start,
                                    softcap=30.0)
     np.testing.assert_allclose(np.asarray(o), np.asarray(r), atol=3e-5)
+
+
+# (Hkv, group, D, page, table width, lens, start, softcap, dtype): a grid
+# step covers min(256 tokens, table) of pages, so widths 1 and 2 give blocks
+# smaller than the rule's, 32+ give the rule's; lengths of 1, ending
+# mid-page and mid-block, a full table, and empty (padded) slots between
+# live ones, across which the next block's copies are not started early
+BLOCK_CASES = {
+    "g2_d256_softcap_start": (2, 2, 256, 16, 64, [700, 300, 1024, 1],
+                              [520, 0, 600, 0], 50.0, jnp.float32),
+    "g8_d128": (1, 8, 128, 16, 32, [1, 17, 264, 512], None, 0.0,
+                jnp.float32),
+    "g12_d128": (2, 12, 128, 16, 128, [367, 1530, 2048], None, 0.0,
+                 jnp.float32),
+    "g12_d128_bf16": (2, 12, 128, 16, 128, [900, 31, 2048], None, 0.0,
+                      jnp.bfloat16),
+    "width1": (2, 4, 64, 16, 1, [1, 9, 16], None, 0.0, jnp.float32),
+    "width2": (2, 4, 64, 16, 2, [5, 32, 17], None, 30.0, jnp.float32),
+    "page8_width64": (2, 2, 64, 8, 64, [300, 255, 512, 8], None, 0.0,
+                      jnp.float32),
+    "empty_slots": (2, 4, 128, 16, 32, [0, 40, 0, 0, 300, 0], None, 0.0,
+                    jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", list(BLOCK_CASES), ids=list(BLOCK_CASES))
+def test_paged_blocks_vs_ref(case):
+    Hkv, group, D, page, maxp, lens, start, softcap, dtype = BLOCK_CASES[case]
+    B = len(lens)
+    pages = B * maxp + 3
+    q = arr(B, Hkv * group, D).astype(dtype)
+    kp = arr(pages, page, Hkv, D).astype(dtype)
+    vp = arr(pages, page, Hkv, D).astype(dtype)
+    tbl = jnp.asarray(R.permutation(pages)[:B * maxp].reshape(B, maxp),
+                      jnp.int32)
+    lens = jnp.asarray(lens, jnp.int32)
+    if start is not None:
+        start = jnp.asarray(start, jnp.int32)
+    o = ops.flash_decode_paged(q, kp, vp, tbl, lens, start=start,
+                               softcap=softcap, interpret=True)
+    r = ref.flash_decode_paged_ref(q, kp, vp, tbl, lens, start=start,
+                                   softcap=softcap)
+    live = np.asarray(lens) > 0          # an empty window has no softmax
+    atol = 3e-5 if dtype == jnp.float32 else 1e-2
+    np.testing.assert_allclose(np.asarray(o, np.float32)[live],
+                               np.asarray(r, np.float32)[live], atol=atol)
+    assert not np.any(np.isnan(np.asarray(o, np.float32)))
 
 
 def test_paged_batch_entry_matches_per_layer():

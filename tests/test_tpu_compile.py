@@ -65,17 +65,27 @@ def _param_shapes(cfg, sharding):
     return jax.tree.map(lambda x: _sds(sharding, x.shape, x.dtype), shapes)
 
 
-@pytest.mark.parametrize("arch", ["gemma2-2b", "starcoder2-3b"])
-def test_paged_decode_kernel_compiles(arch, one_chip, no_persistent_cache):
+@pytest.mark.parametrize("arch,B", [
+    pytest.param("gemma2-2b", 8, id="gemma2-2b"),
+    pytest.param("starcoder2-3b", 8, id="starcoder2-3b"),
+    pytest.param("starcoder2-3b", 16, id="starcoder2-3b-codegen"),
+    pytest.param("yi-9b", 8, id="yi-9b"),
+])
+def test_paged_decode_kernel_compiles(arch, B, one_chip, no_persistent_cache):
+    """One kernel per geometry: group 2 / D 256, group 12 / D 128 (also at
+    the codegen cell's B=16 over a 128-page table), group 8 / D 128.  The
+    benchmark's kernel reading keys on the custom call's name."""
     cfg = get_config(arch)
-    B, P, n_pages = 8, 2048, 128
+    P, n_pages = 2048, 128
     Hq, Hkv, D = cfg.n_q_heads, cfg.n_kv_heads, cfg.head_dim
     pool = _sds(one_chip, (P, Hkv, PAGE, D), jnp.bfloat16)
     compiled = jax.jit(fd.flash_decode_paged_native).lower(
         _sds(one_chip, (B, Hq, D), jnp.bfloat16), pool, pool,
         _sds(one_chip, (B, n_pages), jnp.int32),
         _sds(one_chip, (B,), jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert any("paged_decode_attention" in line
+               for line in compiled.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line)
 
 
 def test_gemma2_decode_loop_kernel_path_fits(one_chip, hbm_bytes,
