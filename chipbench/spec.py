@@ -1,37 +1,47 @@
-"""Find a cell, its configuration, its traffic mix and its metrics by name.
+"""Find a cell, its configuration, its model family, its traffic mix and
+its metrics by name.
 
 Everything is found from ``BENCHMARK.json`` and the files beside this one:
-``configs/<config>.json``, ``traffic/<mix>.json`` and
-``metrics/<metric>.py``.  A new cell or metric is new files and entries;
-no file here names one.
+``configs/<config>.json``, ``families/<family>.py`` (named by the
+configuration's ``"family"`` key), ``traffic/<mix>.json`` and
+``metrics/<metric>.py``.  A new cell, family or metric is new files and
+entries; no file here names one.
 """
 from __future__ import annotations
 
 import dataclasses
 import importlib.util
 import json
+import re
 import sys
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
 
-# published config key -> the program's ModelConfig field it must equal
-WIDTH_FIELDS = {
+# published config key -> the program's ModelConfig field it must equal, for
+# the keys every family shares; a family adds its own (``WIDTH_FIELDS``)
+COMMON_FIELDS = {
     "num_hidden_layers": "n_layers",
     "hidden_size": "d_model",
     "num_attention_heads": "n_q_heads",
     "num_key_value_heads": "n_kv_heads",
     "head_dim": "head_dim",
-    "intermediate_size": "d_ff",
     "vocab_size": "vocab_size",
     "rope_theta": "rope_theta",
     "norm_eps": "norm_eps",
     "tie_word_embeddings": "tie_embeddings",
-    "qkv_bias": "qkv_bias",
-    "mlp_bias": "mlp_bias",
-    "mlp_variant": "mlp_variant",
 }
+# what a family module defines: its published keys beyond the common ones
+# (``WIDTH_FIELDS``), the keys its forward pass reads (``FORWARD_KEYS``), the
+# weights' table and draws (``layout``, ``STD_SCALE``, ``CENTRED``; see
+# weights.py), the reference's forward pass (``logits``; see reference.py),
+# and the counts with counts.py's signatures
+FAMILY_API = ("WIDTH_FIELDS", "FORWARD_KEYS", "layout", "STD_SCALE",
+              "CENTRED", "logits", "params", "matmul_params",
+              "kv_bytes_per_token", "token_flops", "prefill_flops",
+              "decode_attn_bytes")
+NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
 
 
 @dataclasses.dataclass
@@ -68,6 +78,7 @@ def load_cell(name: str, root: Path = ROOT, bench: dict | None = None,
     w = cells[name]
     config = load_json(here / "configs" / f"{w['config']}.json")
     config["name"] = w["config"]
+    family_of(config, here)     # loaded from here; later lookups by name
     traffic = load_json(here / "traffic" / f"{w['traffic']}.json")
     traffic["name"] = w["traffic"]
     return Cell(name=name, config=config, traffic=traffic,
@@ -95,20 +106,57 @@ def load_metric(name: str, here: Path = HERE):
     return mod
 
 
+def load_family(name: str, here: Path = HERE):
+    """The model family ``families/<name>.py``: what ``FAMILY_API`` lists.
+
+    Loaded once per name in a process: every later call for the name gets
+    that module, wherever it looks."""
+    mod_name = f"chipbench_family_{name.replace('.', '_').replace('-', '_')}"
+    if mod_name in sys.modules:
+        return sys.modules[mod_name]
+    path = here / "families" / f"{name}.py"
+    if not NAME.fullmatch(name) or not path.is_file():
+        raise ValueError(f"no model family {name!r}: {path} does not exist")
+    spec = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [a for a in FAMILY_API if not hasattr(mod, a)]
+    if missing:
+        raise ValueError(f"{path} does not define {missing}")
+    sys.modules[mod_name] = mod
+    return mod
+
+
+def family_of(config: dict, here: Path = HERE):
+    """The model family the configuration file names."""
+    if "family" not in config:
+        raise ValueError(f"configuration {config.get('name')!r} names no "
+                         f"model family: give it \"family\", the name of a "
+                         f"file {here / 'families'}/<name>.py")
+    return load_family(config["family"], here)
+
+
 def program_config(config: dict):
     """The program's ``ModelConfig`` for a configuration file.
 
     The repo's config named by ``repo_config`` is taken as it is; keys in
-    ``reduced`` are set from the file.  Every key of ``WIDTH_FIELDS`` in
-    the file must then equal the program's value, so the program cannot
-    drift from the configuration the benchmark states."""
+    ``reduced`` are set from the file.  Every key of ``COMMON_FIELDS`` and
+    of the family's ``WIDTH_FIELDS`` in the file must then equal the
+    program's value, so the program cannot drift from the configuration the
+    benchmark states."""
     from repro.configs import get_config
 
+    fields = {**COMMON_FIELDS, **family_of(config).WIDTH_FIELDS}
+    reduced = config.get("reduced", [])
+    unknown = [k for k in reduced if k not in fields]
+    if unknown:
+        raise ValueError(f"{config['name']}: reduced keys {unknown} map to no "
+                         f"field of the program's config")
     cfg = get_config(config["repo_config"])
-    changes = {WIDTH_FIELDS[k]: config[k] for k in config.get("reduced", [])}
+    changes = {fields[k]: config[k] for k in reduced}
     if changes:
         cfg = dataclasses.replace(cfg, **changes)
-    wrong = {k: (config[k], getattr(cfg, f)) for k, f in WIDTH_FIELDS.items()
+    wrong = {k: (config[k], getattr(cfg, f)) for k, f in fields.items()
              if k in config and getattr(cfg, f) != config[k]}
     if wrong:
         raise ValueError(f"{config['name']}: the program's config differs "

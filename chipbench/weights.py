@@ -14,54 +14,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import spec
+
 # standard deviations: matrices 1/sqrt(fan_in); the embedding and the output
 # head 0.02; biases and the norm weights' offsets from 1 are small but not
-# zero, so a path that drops them shows in the logits
+# zero, so a path that drops them shows in the logits; a family may scale a
+# leaf's std (``STD_SCALE``) and centre a matrix's columns (``CENTRED``)
 EMBED_STD = 0.02
 BIAS_STD = 0.1
 NORM_STD = 0.1
-# Drawn as above alone, a deep stack collapses: every position's last
-# hidden state tends to one vector, so all positions of all prompts put
-# the same one or two tokens first, and a comparison of served tokens
-# makes one decision per request.  Two changes keep positions apart:
-# ``w_down``'s columns sum to zero, so the GELU's positive mean adds no
-# vector common to every position; and the attention output is scaled
-# down, so its averaging over the context does not pull them together.
-ATTN_OUT_SCALE = 0.3
-CENTRED = ("blocks/mlp/w_down",)
 
 
 def layout(c: dict) -> dict:
     """{leaf path: (shape, kind)} of the served weights for config file
-    ``c``; kind is "matrix", "embed", "bias" or "norm"."""
-    L, d = c["num_hidden_layers"], c["hidden_size"]
-    qd = c["num_attention_heads"] * c["head_dim"]
-    kvd = c["num_key_value_heads"] * c["head_dim"]
-    ff, V = c["intermediate_size"], c["vocab_size"]
-    out = {
-        "embed": ((V, d), "embed"),
-        "final_norm": ((d,), "norm"),
-        "blocks/ln1": ((L, d), "norm"),
-        "blocks/ln2": ((L, d), "norm"),
-        "blocks/attn/wq": ((L, d, qd), "matrix"),
-        "blocks/attn/wk": ((L, d, kvd), "matrix"),
-        "blocks/attn/wv": ((L, d, kvd), "matrix"),
-        "blocks/attn/wo": ((L, qd, d), "matrix"),
-        "blocks/mlp/w_up": ((L, d, ff), "matrix"),
-        "blocks/mlp/w_down": ((L, ff, d), "matrix"),
-    }
-    if c["mlp_variant"] in ("swiglu", "geglu"):
-        out["blocks/mlp/w_gate"] = ((L, d, ff), "matrix")
-    if c["qkv_bias"]:
-        out["blocks/attn/bq"] = ((L, qd), "bias")
-        out["blocks/attn/bk"] = ((L, kvd), "bias")
-        out["blocks/attn/bv"] = ((L, kvd), "bias")
-    if c["mlp_bias"]:
-        out["blocks/mlp/b_up"] = ((L, ff), "bias")
-        out["blocks/mlp/b_down"] = ((L, d), "bias")
-    if not c["tie_word_embeddings"]:
-        out["lm_head"] = ((d, V), "embed")
-    return out
+    ``c``, from its family's table; kind is "matrix", "embed", "bias" or
+    "norm"."""
+    return spec.family_of(c).layout(c)
 
 
 def nest(flat: dict) -> dict:
@@ -114,22 +82,23 @@ def make(c: dict, seed: int, dtype=jnp.bfloat16, shardings=None) -> dict:
 
     ``shardings``: optional tree (as ``nest``ed paths) of shardings to
     place each leaf in as it is made."""
-    spec = layout(c)
-    paths = sorted(spec)
+    family = spec.family_of(c)
+    table = family.layout(c)
+    paths = sorted(table)
 
     def build(key):
         keys = jax.random.split(key, len(paths))
         flat = {}
         for k, path in zip(keys, paths):
-            shape, kind = spec[path]
+            shape, kind = table[path]
             std = (1.0 / np.sqrt(shape[-2]) if kind == "matrix" else
                    {"embed": EMBED_STD, "bias": BIAS_STD,
                     "norm": NORM_STD}[kind])
-            if path == "blocks/attn/wo":
-                std *= ATTN_OUT_SCALE
+            if path in family.STD_SCALE:
+                std *= family.STD_SCALE[path]
             # drawn in the served dtype: no float32 copy of a whole leaf
             x = jax.random.normal(k, shape, dtype) * jnp.asarray(std, dtype)
-            if path in CENTRED:
+            if path in family.CENTRED:
                 mean = jnp.mean(x, axis=-2, keepdims=True, dtype=jnp.float32)
                 x = x - mean.astype(dtype)
             flat[path] = x
