@@ -44,9 +44,12 @@ class ModelProfile:
     head_dim: int
     d_ff: int
     vocab: int
-    # MoE (0 experts == dense)
+    # MoE (0 experts == dense): the experts held and the mean number a
+    # token passes through among them; the router scores
+    # ``router_experts`` (0: ``n_experts``)
     n_experts: int = 0
-    top_k: int = 0
+    top_k: float = 0
+    router_experts: int = 0
     # SSM / hybrid
     ssm_state: int = 0            # d_state per head (0 == no SSM path)
     ssm_heads: int = 0
@@ -73,10 +76,14 @@ class ModelProfile:
         return d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
 
     @property
+    def router_params(self) -> int:
+        return self.d_model * (self.router_experts or self.n_experts)
+
+    @property
     def mlp_params_per_layer(self) -> int:
         if self.n_experts > 0:
-            router = self.d_model * self.n_experts
-            return router + self.n_experts * 3 * self.d_model * self.d_ff
+            return (self.router_params
+                    + self.n_experts * 3 * self.d_model * self.d_ff)
         if self.d_ff == 0:
             return 0
         return 3 * self.d_model * self.d_ff  # SwiGLU: gate, up, down
@@ -84,8 +91,8 @@ class ModelProfile:
     @property
     def mlp_active_params_per_layer(self) -> int:
         if self.n_experts > 0:
-            router = self.d_model * self.n_experts
-            return router + self.top_k * 3 * self.d_model * self.d_ff
+            return int(self.router_params
+                       + self.top_k * 3 * self.d_model * self.d_ff)
         return self.mlp_params_per_layer
 
     @property

@@ -142,12 +142,12 @@ def _pages_per_block(page: int, max_pages: int) -> int:
     return ppb
 
 
-def _paged_kernel(lens_ref, start_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
-                  k_buf, v_buf, k_sem, v_sem, flight, m_scr, l_scr, acc_scr,
-                  *, scale, softcap, ppb, n_blocks):
+def _paged_kernel(lens_ref, start_ref, table_ref, layer_ref, q_ref, k_hbm,
+                  v_hbm, o_ref, k_buf, v_buf, k_sem, v_sem, flight, m_scr,
+                  l_scr, acc_scr, *, scale, softcap, ppb, n_blocks):
     b, h, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     n_b, n_h = pl.num_programs(0), pl.num_programs(1)
-    page = k_hbm.shape[2]
+    page = k_hbm.shape[3]
     bk = ppb * page
 
     def live_pages(bb):
@@ -170,7 +170,7 @@ def _paged_kernel(lens_ref, start_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
                 for pool, buf, sem in ((k_hbm, k_buf, k_sem),
                                        (v_hbm, v_buf, v_sem)):
                     cp = pltpu.make_async_copy(
-                        pool.at[table_ref[bb, p], hh],
+                        pool.at[layer_ref[0], table_ref[bb, p], hh],
                         buf.at[slot, pl.ds(i * page, page)], sem.at[slot])
                     if wait:
                         cp.wait()
@@ -256,14 +256,20 @@ def _paged_kernel(lens_ref, start_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 def _paged_call(q: jax.Array, kt: jax.Array, vt: jax.Array,
                 block_table: jax.Array, lens: jax.Array, start: jax.Array,
-                *, softcap: float, scale: float, interpret: bool) -> jax.Array:
+                *, softcap: float, scale: float, interpret: bool,
+                layer: jax.Array | None = None) -> jax.Array:
     """Core pallas_call over kernel-native layouts.
 
-    q: [B, Hq, D]; kt/vt: [P, Hkv, page, D]; block_table: [B, max_pages];
+    q: [B, Hq, D]; kt/vt: [P, Hkv, page, D], or with ``layer`` (a traced
+    scalar) every layer's pools [L, P, Hkv, page, D], of which the kernel
+    reads layer ``layer`` in place; block_table: [B, max_pages];
     lens/start: [B].  Returns [B, Hq, D].
     """
+    if layer is None:
+        kt, vt, layer = kt[None], vt[None], 0
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     B, Hq, D = q.shape
-    _, Hkv, page, _ = kt.shape
+    _, _, Hkv, page, _ = kt.shape
     group = Hq // Hkv
     max_pages = block_table.shape[1]
     ppb = _pages_per_block(page, max_pages)
@@ -275,9 +281,9 @@ def _paged_call(q: jax.Array, kt: jax.Array, vt: jax.Array,
     # the group's query heads as one block whose last two dims are the
     # array's, which Mosaic takes for any group size
     q_spec = pl.BlockSpec((1, 1, group, D),
-                          lambda b, h, j, lens, start, tbl: (b, h, 0, 0))
+                          lambda b, h, j, *prefetched: (b, h, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,                     # lens, start, block_table
+        num_scalar_prefetch=4,             # lens, start, block_table, layer
         grid=(B, Hkv, n_blocks),
         in_specs=[q_spec,
                   pl.BlockSpec(memory_space=pl.ANY),   # pools stay in HBM
@@ -305,7 +311,8 @@ def _paged_call(q: jax.Array, kt: jax.Array, vt: jax.Array,
         interpret=interpret,
         name="paged_decode_attention",
     )(lens.astype(jnp.int32), start.astype(jnp.int32),
-      block_table.astype(jnp.int32), q.reshape(B, Hkv, group, D), kt, vt)
+      block_table.astype(jnp.int32), layer, q.reshape(B, Hkv, group, D), kt,
+      vt)
     return out.reshape(B, Hq, D)
 
 
@@ -315,11 +322,14 @@ def flash_decode_paged_native(q: jax.Array, k_pages: jax.Array,
                               start: jax.Array | None = None,
                               softcap: float = 0.0,
                               scale: float | None = None,
-                              interpret: bool = False) -> jax.Array:
+                              interpret: bool = False,
+                              layer: jax.Array | None = None) -> jax.Array:
     """Paged decode over kernel-native pools (the serving engine's layout).
 
     q: [B, Hq, D]; k_pages/v_pages: [num_pages, Hkv, page, D] — already in
-    kernel layout, so no per-call transpose.  Other args as
+    kernel layout, so no per-call transpose — or, with ``layer``, the
+    stacked pools of every layer [L, num_pages, Hkv, page, D], read at
+    that layer in place (no slice of the stack is copied).  Other args as
     ``flash_decode_paged``.  Returns [B, Hq, D].
     """
     D = q.shape[-1]
@@ -329,7 +339,7 @@ def flash_decode_paged_native(q: jax.Array, k_pages: jax.Array,
         start = jnp.zeros_like(lens)
     return _paged_call(q, k_pages, v_pages, block_table, lens, start,
                        softcap=softcap, scale=scale,
-                       interpret=interpret)
+                       interpret=interpret, layer=layer)
 
 
 def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,

@@ -122,7 +122,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool,
         def fn(params, inputs):
             return prefill(params, run_cfg,
                            tokens=inputs.get("tokens"),
-                           embeds=inputs.get("embeds"))
+                           embeds=inputs.get("embeds"))[:2]
         in_batch = {k: v for k, v in ins.items()}
         in_specs = (pspecs, jax.tree.map(
             lambda x: P(batch_axes, *([None] * (len(x.shape) - 1))), in_batch))

@@ -303,6 +303,9 @@ def pad_attention_params(params, cfg: ModelConfig, padded: ModelConfig):
     """
     if padded.n_q_heads == cfg.n_q_heads and padded.n_kv_heads == cfg.n_kv_heads:
         return params
+    if cfg.qk_norm and cfg.qk_norm_scope == "proj":
+        # zero heads would change the RMS over the whole projection
+        raise NotImplementedError("head padding with whole-projection QK-norm")
     D = cfg.head_dim
     q_old, q_new = cfg.n_q_heads, padded.n_q_heads
     kv_old, kv_new = cfg.n_kv_heads, padded.n_kv_heads

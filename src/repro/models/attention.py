@@ -36,8 +36,9 @@ def init_attention(key: jax.Array, cfg: ModelConfig, dtype) -> dict:
         p["bk"] = jnp.zeros((kv_dim,), dtype)
         p["bv"] = jnp.zeros((kv_dim,), dtype)
     if cfg.qk_norm:
-        p["q_norm"] = jnp.zeros((cfg.head_dim,), dtype)
-        p["k_norm"] = jnp.zeros((cfg.head_dim,), dtype)
+        whole = cfg.qk_norm_scope == "proj"
+        p["q_norm"] = jnp.zeros((q_dim if whole else cfg.head_dim,), dtype)
+        p["k_norm"] = jnp.zeros((kv_dim if whole else cfg.head_dim,), dtype)
     return p
 
 
@@ -48,10 +49,13 @@ def _project_qkv(x, p, cfg: ModelConfig, positions):
     v = x @ p["wv"]
     if cfg.qkv_bias:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
+    if cfg.qk_norm and cfg.qk_norm_scope == "proj":
+        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     q = q.reshape(B, S, cfg.n_q_heads, cfg.head_dim)
     k = k.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
     v = v.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-    if cfg.qk_norm:
+    if cfg.qk_norm and cfg.qk_norm_scope == "head":
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     if cfg.pos_embedding == "rope":
@@ -187,7 +191,8 @@ def paged_decode_attention(x: jax.Array, p: dict, cfg: ModelConfig,
                            k_pages: jax.Array, v_pages: jax.Array,
                            block_table: jax.Array, lens: jax.Array,
                            is_local: jax.Array | bool = False, *,
-                           impl: str = "jnp", interpret: bool = False
+                           impl: str = "jnp", interpret: bool = False,
+                           layer: jax.Array | None = None
                            ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One decode step directly against the paged KV pool (gather-free).
 
@@ -201,6 +206,9 @@ def paged_decode_attention(x: jax.Array, p: dict, cfg: ModelConfig,
       x: [B, 1, d_model] current token embedding.
       k_pages / v_pages: [P, Hkv, page, D] one layer's pool, kernel-native
         layout; the new K/V token is scattered into its page in place.
+        With ``layer`` (a traced scalar; ``impl="kernel"`` only): every
+        layer's pools [L, P, Hkv, page, D], written and read at that layer
+        in place, so a layer loop that carries them copies no pool.
       block_table: [B, n_pages] physical page ids (padded rows may point at
         a trash page — the scatter then lands there harmlessly).
       lens: [B] number of tokens already cached; the new token is written at
@@ -215,8 +223,8 @@ def paged_decode_attention(x: jax.Array, p: dict, cfg: ModelConfig,
     # sharded replicas: q by (tp) heads, the new K/V token by KV heads, so
     # the page scatter below stays local to the head shard that owns it
     q = logical(q, "batch", None, "heads", None)
-    page = k_pages.shape[2]
-    Hkv = k_pages.shape[1]
+    page = k_pages.shape[-2]
+    Hkv = k_pages.shape[-3]
     dpad = k_pages.shape[-1] - cfg.head_dim   # pool head_pad (kernel path)
     kn, vn = k_new[:, 0], v_new[:, 0]
     kn = logical(kn, "batch", "kv_heads", None)
@@ -227,10 +235,11 @@ def paged_decode_attention(x: jax.Array, p: dict, cfg: ModelConfig,
     pid = block_table[jnp.arange(B), pos // page]         # [B]
     off = pos % page
     hidx = jnp.arange(Hkv)[None, :]
-    k_pages = k_pages.at[pid[:, None], hidx, off[:, None]].set(
-        kn.astype(k_pages.dtype))
-    v_pages = v_pages.at[pid[:, None], hidx, off[:, None]].set(
-        vn.astype(v_pages.dtype))
+    at = (pid[:, None], hidx, off[:, None])
+    if layer is not None:
+        at = (layer, *at)
+    k_pages = k_pages.at[at].set(kn.astype(k_pages.dtype))
+    v_pages = v_pages.at[at].set(vn.astype(v_pages.dtype))
 
     len_att = pos + 1
     if cfg.local_window > 0:
@@ -247,7 +256,7 @@ def paged_decode_attention(x: jax.Array, p: dict, cfg: ModelConfig,
             qk, k_pages, v_pages, block_table, len_att, start=start,
             softcap=float(cfg.attn_logit_softcap),
             scale=1.0 / np.sqrt(cfg.head_dim),
-            interpret=interpret)[..., :cfg.head_dim]
+            interpret=interpret, layer=layer)[..., :cfg.head_dim]
     else:
         out = paged_attention_jnp(q[:, 0], k_pages, v_pages, block_table,
                                   len_att, start, cfg)

@@ -34,6 +34,10 @@ class ModelConfig:
     qkv_bias: bool = False
     mlp_bias: bool = False
     qk_norm: bool = False
+    # QK-norm form: "head" normalises each head with a [head_dim] weight
+    # (Chameleon); "proj" normalises the whole q and k projections with
+    # [q_dim] and [kv_dim] weights before the split into heads (OLMoE)
+    qk_norm_scope: str = "head"
     attn_logit_softcap: float = 0.0    # gemma2
     final_logit_softcap: float = 0.0   # gemma2
     local_window: int = 0              # >0: alternate local/global (gemma2)
@@ -42,10 +46,14 @@ class ModelConfig:
     norm_eps: float = 1e-6
     mlp_variant: str = "swiglu"        # swiglu | geglu | gelu (2-matmul)
 
-    # MoE
+    # MoE: the router scores all ``n_experts``; this program holds the
+    # contiguous range [expert_offset, expert_offset + experts_held) of
+    # them (0: all), one chip's share of an expert-parallel deployment
     n_experts: int = 0
     top_k: int = 0
-    moe_capacity_factor: float = 1.25
+    experts_held: int = 0
+    expert_offset: int = 0
+    norm_topk_prob: bool = True        # renormalise the top-k weights
 
     # SSM / hybrid
     ssm_state: int = 0
@@ -89,6 +97,11 @@ class ModelConfig:
         return self.n_experts > 0
 
     @property
+    def n_held(self) -> int:
+        """Experts whose weights this program holds."""
+        return self.experts_held or self.n_experts
+
+    @property
     def q_dim(self) -> int:
         return self.n_q_heads * self.head_dim
 
@@ -117,6 +130,12 @@ class ModelConfig:
     # -- cost-model bridge ------------------------------------------------
 
     def profile(self) -> ModelProfile:
+        """The cost model's view: the held experts' share of the model.
+        A token passes through ``top_k * n_held / n_experts`` held experts
+        on average (all ``top_k`` when every expert is held)."""
+        top_k = self.top_k
+        if self.is_moe and self.n_held != self.n_experts:
+            top_k = self.top_k * self.n_held / self.n_experts
         return ModelProfile(
             name=self.name,
             n_layers=self.n_layers,
@@ -126,8 +145,9 @@ class ModelConfig:
             head_dim=self.head_dim,
             d_ff=self.d_ff,
             vocab=self.vocab_size,
-            n_experts=self.n_experts,
-            top_k=self.top_k,
+            n_experts=self.n_held,
+            top_k=top_k,
+            router_experts=self.n_experts,
             ssm_state=self.ssm_state,
             ssm_heads=self.ssm_heads,
             ssm_head_dim=self.ssm_head_dim,
@@ -161,7 +181,8 @@ class ModelConfig:
         if self.is_moe:
             changes["n_experts"] = n_experts if n_experts is not None else 8
             changes["top_k"] = top_k if top_k is not None else 2
-            changes["moe_capacity_factor"] = 2.0  # drop-free smoke tests
+            changes["experts_held"] = 0
+            changes["expert_offset"] = 0
         if self.has_ssm:
             changes["ssm_state"] = 16
             changes["ssm_heads"] = 4

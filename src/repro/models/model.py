@@ -14,6 +14,12 @@ Entry points:
   decode_step(params, cfg, token, cache) -> (logits, DecodeCache)
   decode_loop_paged(params, cfg, tokens, state, key, step0, horizon)
       -> ([B, horizon] tokens, PagedDecodeState)   (fused multi-step decode)
+
+The serving programs (``prefill``, ``prefill_chunk``,
+``decode_loop_paged``) of an expert model (``cfg.is_moe``) also return its
+routing counts for the execution, last (``route_counts``): routed (token,
+expert) pairs per held expert summed over layers and steps, and the
+largest count of one expert in one layer and step.
 """
 from __future__ import annotations
 
@@ -171,7 +177,8 @@ def _mixer(x, bp, cfg: ModelConfig, layer_idx, positions, mode,
         elif mode == "decode" and paged is not None:
             attn_out, new_k, new_v = attn_lib.paged_decode_attention(
                 x, bp["attn"], cfg, kv[0], kv[1], paged["table"], pos,
-                is_local, impl=paged["impl"], interpret=paged["interpret"])
+                is_local, impl=paged["impl"], interpret=paged["interpret"],
+                layer=paged.get("layer"))
         elif mode == "decode":
             attn_out, new_k, new_v = attn_lib.decode_attention(
                 x, bp["attn"], cfg, kv[0], kv[1], pos, is_local)
@@ -197,9 +204,25 @@ def _mixer(x, bp, cfg: ModelConfig, layer_idx, positions, mode,
     return out, (new_k, new_v), (new_state, new_conv)
 
 
+def route_counts(load):
+    """load [..., L, n_held] (one layer's routed pairs per held expert, per
+    step) -> (pairs per held expert over layers and steps [n_held], the
+    largest single-layer count)."""
+    per_expert = load.reshape(-1, load.shape[-1]).sum(0)
+    return per_expert, jnp.max(load)
+
+
+def _routed(out: tuple, load) -> tuple:
+    """A serving program's ``out``, with ``route_counts(load)`` last where
+    the model has expert layers (``load`` is None where it has none)."""
+    return out if load is None else (*out, route_counts(load))
+
+
 def _block(x, bp, cfg: ModelConfig, layer_idx, positions, mode,
            kv=None, ssm_state=None, conv=None, pos=None, with_aux=False,
-           paged=None):
+           paged=None, valid=None):
+    """One layer; returns (x, new_kv, new_ssm, aux, load), where load is
+    the expert layer's routed pairs per held expert (None without one)."""
     h = rms_norm(x, bp["ln1"], cfg.norm_eps)
     mix, new_kv, new_ssm = _mixer(h, bp, cfg, layer_idx, positions, mode,
                                   kv, ssm_state, conv, pos, paged)
@@ -207,10 +230,11 @@ def _block(x, bp, cfg: ModelConfig, layer_idx, positions, mode,
         mix = rms_norm(mix, bp["post_ln1"], cfg.norm_eps)
     x = x + mix
     aux = jnp.zeros((), jnp.float32)
+    load = None
     if cfg.is_moe or cfg.d_ff > 0:
         h2 = rms_norm(x, bp["ln2"], cfg.norm_eps)
         if cfg.is_moe:
-            m = moe_lib.moe_block(h2, bp["moe"], cfg)
+            m, load = moe_lib.moe_block(h2, bp["moe"], cfg, valid)
             if with_aux:
                 aux = moe_lib.load_balance_loss(h2, bp["moe"], cfg)
         else:
@@ -221,7 +245,7 @@ def _block(x, bp, cfg: ModelConfig, layer_idx, positions, mode,
     # Residual-stream boundary: "act_seq" is sequence-parallel (sharded
     # over `model`) in training plans to cut layer-boundary activation memory.
     x = logical(x, "batch", "act_seq", "d_model")
-    return x, new_kv, new_ssm, aux
+    return x, new_kv, new_ssm, aux, load
 
 
 # --------------------------------------------------------------------------
@@ -245,8 +269,10 @@ def embed_inputs(params, cfg: ModelConfig, tokens=None, embeds=None,
 def lm_logits(params, cfg: ModelConfig, x):
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = x @ head
-    logits = softcap(logits.astype(jnp.float32), cfg.final_logit_softcap)
+    # accumulated and kept in float32: logits rounded to bf16 move the
+    # choice between near-tied tokens (a step of 1/64 at logits of 2-4)
+    logits = jnp.dot(x, head, preferred_element_type=jnp.float32)
+    logits = softcap(logits, cfg.final_logit_softcap)
     return logical(logits, "batch", "seq", "vocab")
 
 
@@ -267,8 +293,8 @@ def forward(params, cfg: ModelConfig, tokens=None, embeds=None,
     def body(carry, scanned):
         x, aux_sum = carry
         bp, layer_idx = scanned
-        x, _, _, aux = _block(x, bp, cfg, layer_idx, positions, "full",
-                              with_aux=with_aux)
+        x, _, _, aux, _ = _block(x, bp, cfg, layer_idx, positions, "full",
+                                 with_aux=with_aux)
         return (x, aux_sum + aux), None
 
     if remat:
@@ -289,7 +315,8 @@ def forward(params, cfg: ModelConfig, tokens=None, embeds=None,
 
 
 def prefill(params, cfg: ModelConfig, tokens=None, embeds=None):
-    """Returns (last-token logits [B, Vpad], DecodeCache at length S)."""
+    """Returns (last-token logits [B, Vpad], DecodeCache at length S), and
+    an expert model's ``route_counts`` last."""
     B, S = (tokens.shape if tokens is not None else embeds.shape[:2])
     positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S)).astype(jnp.int32)
     x = embed_inputs(params, cfg, tokens, embeds, positions)
@@ -322,8 +349,10 @@ def prefill(params, cfg: ModelConfig, tokens=None, embeds=None):
         x = x + mix
         if cfg.is_moe or cfg.d_ff > 0:
             h2 = rms_norm(x, bp["ln2"], cfg.norm_eps)
-            m = (moe_lib.moe_block(h2, bp["moe"], cfg) if cfg.is_moe
-                 else mlp(h2, bp["mlp"], cfg.mlp_variant))
+            if cfg.is_moe:
+                m, caches["load"] = moe_lib.moe_block(h2, bp["moe"], cfg)
+            else:
+                m = mlp(h2, bp["mlp"], cfg.mlp_variant)
             if cfg.sandwich_norm:
                 m = rms_norm(m, bp["post_ln2"], cfg.norm_eps)
             x = x + m
@@ -338,7 +367,7 @@ def prefill(params, cfg: ModelConfig, tokens=None, embeds=None):
     cache = DecodeCache(
         k=caches.get("k"), v=caches.get("v"),
         ssm=caches.get("ssm"), conv=caches.get("conv"), pos=pos)
-    return logits, cache
+    return _routed((logits, cache), caches.get("load"))
 
 
 def prefill_chunk(params, cfg: ModelConfig, tokens, k, v, block_table,
@@ -359,7 +388,8 @@ def prefill_chunk(params, cfg: ModelConfig, tokens, k, v, block_table,
       block_table: [B, n_pages] page ids covering ``start + C`` tokens.
       start: scalar int32 tokens already resident; n_valid: scalar int32.
     Returns: (logits at position ``start + n_valid - 1`` [B, Vpad] fp32,
-      new k, new v).
+      new k, new v), and an expert model's ``route_counts`` of the valid
+      positions last.
 
     SSM/hybrid architectures are not supported (the SSD scan has no
     per-position state checkpoint to resume a bucketed chunk from); callers
@@ -374,19 +404,24 @@ def prefill_chunk(params, cfg: ModelConfig, tokens, k, v, block_table,
     x = embed_inputs(params, cfg, tokens, None, positions)
     x = logical(x, "batch", "seq", "d_model")
     paged = {"table": block_table, "n_valid": n_valid, "trash": trash_page}
+    valid = jnp.broadcast_to(jnp.arange(C)[None, :] < n_valid, (B, C))
 
     def body(x, scanned):
         bp, layer_idx, k_l, v_l = scanned
-        x, new_kv, _, _ = _block(x, bp, cfg, layer_idx, positions, "chunk",
-                                 kv=(k_l, v_l), pos=start, paged=paged)
-        return x, {"k": new_kv[0], "v": new_kv[1]}
+        x, new_kv, _, _, load = _block(x, bp, cfg, layer_idx, positions,
+                                       "chunk", kv=(k_l, v_l), pos=start,
+                                       paged=paged, valid=valid)
+        ys = {"k": new_kv[0], "v": new_kv[1]}
+        if load is not None:
+            ys["load"] = load
+        return x, ys
 
     x, ys = jax.lax.scan(
         body, x, (params["blocks"], jnp.arange(cfg.n_layers), k, v),
         unroll=cfg.scan_unroll)
     x_last = jnp.take(x, n_valid - 1, axis=1)[:, None]   # [B, 1, d]
     logits = lm_logits(params, cfg, x_last)[:, 0]
-    return logits, ys["k"], ys["v"]
+    return _routed((logits, ys["k"], ys["v"]), ys.get("load"))
 
 
 # --------------------------------------------------------------------------
@@ -395,40 +430,58 @@ def prefill_chunk(params, cfg: ModelConfig, tokens, k, v, block_table,
 
 
 def _decode_core(params, cfg: ModelConfig, tokens, embeds, pos,
-                 k, v, ssm, conv, paged):
+                 k, v, ssm, conv, paged, valid=None):
     """Shared decode-step body: embed, layer scan, logits.
 
     ``paged=None`` runs dense cached attention over k/v [L, B, Smax, Hkv, D];
-    a paged dict (see ``_mixer``) runs paged attention over pools.
-    Returns (logits [B, Vpad] fp32, per-layer ys dict of new state).
+    a paged dict (see ``_mixer``) runs paged attention over pools.  The
+    Pallas kernel path carries every layer's pools through the layer loop
+    and writes and reads them at the layer in place: a loop that took a
+    layer's pool in and stacked the updated pools out would write a second
+    copy of them every step.
+    Returns (logits [B, Vpad] fp32, per-layer ys dict of new state, with
+    the expert layers' ``load`` [L, n_held] of the ``valid`` [B] rows;
+    ``k``/``v`` are the new pools).
     """
     positions = pos[:, None]
     x = embed_inputs(params, cfg, None if tokens is None else tokens[:, None],
                      embeds, positions)
     x = logical(x, "batch", "seq", "d_model")
+    carried = (paged is not None and paged["impl"] == "kernel"
+               and cfg.has_attn)
 
-    def body(x, scanned):
+    def body(carry, scanned):
         bp, layer_idx, k_l, v_l, ssm_l, conv_l = scanned
-        x, new_kv, new_ssm, _ = _block(
+        x = carry[0]
+        lpaged = paged
+        if carried:
+            k_l, v_l = carry[1:]
+            lpaged = {**paged, "layer": layer_idx}
+        x, new_kv, new_ssm, _, load = _block(
             x, bp, cfg, layer_idx, positions, "decode",
             kv=(k_l, v_l), ssm_state=ssm_l, conv=conv_l, pos=pos,
-            paged=paged)
+            paged=lpaged, valid=None if valid is None else valid[:, None])
         ys = {}
-        if cfg.has_attn:
+        if cfg.has_attn and not carried:
             ys["k"], ys["v"] = new_kv
         if cfg.has_ssm:
             ys["ssm"], ys["conv"] = new_ssm
-        return x, ys
+        if load is not None:
+            ys["load"] = load
+        return (x, *new_kv) if carried else (x,), ys
 
     L = cfg.n_layers
     dummy = jnp.zeros((L,), jnp.int32)
     xs = (params["blocks"], jnp.arange(L),
-          k if k is not None else dummy,
-          v if v is not None else dummy,
+          dummy if k is None or carried else k,
+          dummy if v is None or carried else v,
           ssm if ssm is not None else dummy,
           conv if conv is not None else dummy)
-    x, ys = jax.lax.scan(body, x, xs, unroll=cfg.scan_unroll)
-    return lm_logits(params, cfg, x)[:, 0], ys
+    init = (x, k, v) if carried else (x,)
+    carry, ys = jax.lax.scan(body, init, xs, unroll=cfg.scan_unroll)
+    if carried:
+        ys["k"], ys["v"] = carry[1:]
+    return lm_logits(params, cfg, carry[0])[:, 0], ys
 
 
 def decode_step(params, cfg: ModelConfig, tokens, cache: DecodeCache,
@@ -464,19 +517,29 @@ def decode_step_paged(params, cfg: ModelConfig, tokens,
       attn_impl: "kernel" (Pallas paged kernel) or "jnp" (exact fallback).
     Returns: (logits [B, Vpad] fp32, updated PagedDecodeState with lens+1)
     """
+    return _step_paged(params, cfg, tokens, state, embeds, attn_impl,
+                       interpret)[:2]
+
+
+def _step_paged(params, cfg: ModelConfig, tokens, state: PagedDecodeState,
+                embeds, attn_impl, interpret, valid=None):
+    """``decode_step_paged``, with the expert layers' load [L, n_held] of
+    the ``valid`` [B] rows (None without expert layers) last."""
     paged = {"table": state.block_table, "impl": attn_impl,
              "interpret": interpret}
     logits, ys = _decode_core(params, cfg, tokens, embeds, state.lens,
-                              state.k, state.v, state.ssm, state.conv, paged)
+                              state.k, state.v, state.ssm, state.conv, paged,
+                              valid)
     new_state = PagedDecodeState(
         k=ys.get("k", state.k), v=ys.get("v", state.v),
         block_table=state.block_table, lens=state.lens + 1,
         ssm=ys.get("ssm", state.ssm), conv=ys.get("conv", state.conv))
-    return logits, new_state
+    return logits, new_state, ys.get("load")
 
 
 def _decode_core_buffered(params, cfg: ModelConfig, tokens, pos, k, v,
-                          ssm, conv, table, kh, vh, step_idx, pool_lens):
+                          ssm, conv, table, kh, vh, step_idx, pool_lens,
+                          valid=None):
     """One buffered decode step: like ``_decode_core`` in paged mode, but
     the pools are consumed READ-ONLY (scan xs of the layer scan — never
     copied) and each layer's new K/V token is written to its row of the
@@ -491,15 +554,17 @@ def _decode_core_buffered(params, cfg: ModelConfig, tokens, pos, k, v,
         bp, layer_idx, k_l, v_l, kh_l, vh_l, ssm_l, conv_l = scanned
         paged = {"impl": "buffered", "table": table, "kh": kh_l, "vh": vh_l,
                  "step": step_idx, "pool_lens": pool_lens}
-        x, new_kv, new_ssm, _ = _block(
+        x, new_kv, new_ssm, _, load = _block(
             x, bp, cfg, layer_idx, positions, "decode",
             kv=(k_l, v_l), ssm_state=ssm_l, conv=conv_l, pos=pos,
-            paged=paged)
+            paged=paged, valid=None if valid is None else valid[:, None])
         ys = {}
         if cfg.has_attn:
             ys["k"], ys["v"] = new_kv          # updated buffer rows
         if cfg.has_ssm:
             ys["ssm"], ys["conv"] = new_ssm
+        if load is not None:
+            ys["load"] = load
         return x, ys
 
     L = cfg.n_layers
@@ -518,7 +583,7 @@ def _decode_core_buffered(params, cfg: ModelConfig, tokens, pos, k, v,
 def decode_loop_paged(params, cfg: ModelConfig, tokens,
                       state: PagedDecodeState, key, step0, horizon: int, *,
                       attn_impl: str = "jnp", interpret: bool = False,
-                      temperature: float = 0.0):
+                      temperature: float = 0.0, valid=None):
     """``horizon`` fused decode steps, entirely on device (``lax.scan``).
 
     Each scan iteration runs one full paged decode step — attention over
@@ -550,8 +615,11 @@ def decode_loop_paged(params, cfg: ModelConfig, tokens,
       state: PagedDecodeState at the pre-loop lengths.
       horizon: static step count (callers bucket it to keep compilations
         O(log max_horizon)).
+      valid: [B] bool rows that are traffic (default all); only they count
+        in the routing counts.
     Returns: (tokens [B, horizon] int32, PagedDecodeState with lens +
-      horizon)
+      horizon), and an expert model's ``route_counts`` over the horizon
+      last.
     """
     from repro.models.sampling import sample, step_key
 
@@ -559,16 +627,15 @@ def decode_loop_paged(params, cfg: ModelConfig, tokens,
         # scatter-first loop: pools (if any) ride the scan carry
         def body(carry, i):
             toks, st = carry
-            logits, st = decode_step_paged(params, cfg, toks, st,
-                                           attn_impl=attn_impl,
-                                           interpret=interpret)
+            logits, st, load = _step_paged(params, cfg, toks, st, None,
+                                           attn_impl, interpret, valid)
             toks = sample(logits, cfg, step_key(key, step0 + i),
                           temperature=temperature)
-            return (toks, st), toks
+            return (toks, st), (toks, load)
 
-        (_, state), toks_h = jax.lax.scan(
+        (_, state), (toks_h, loads) = jax.lax.scan(
             body, (tokens, state), jnp.arange(horizon, dtype=jnp.int32))
-        return jnp.moveaxis(toks_h, 0, 1), state
+        return _routed((jnp.moveaxis(toks_h, 0, 1), state), loads)
 
     # buffered loop (jnp path): pools stay out of the carry
     B = tokens.shape[0]
@@ -585,14 +652,15 @@ def decode_loop_paged(params, cfg: ModelConfig, tokens,
         toks, lens, kh, vh, ssm, conv = carry
         logits, ys = _decode_core_buffered(
             params, cfg, toks, lens, state.k, state.v, ssm, conv,
-            state.block_table, kh, vh, i, pool_lens)
+            state.block_table, kh, vh, i, pool_lens, valid)
         toks = sample(logits, cfg, step_key(key, step0 + i),
                       temperature=temperature)
         return (toks, lens + 1, ys["k"], ys["v"],
-                ys.get("ssm", ssm), ys.get("conv", conv)), toks
+                ys.get("ssm", ssm), ys.get("conv", conv)), (toks,
+                                                            ys.get("load"))
 
     init = (tokens, state.lens, kh, vh, state.ssm, state.conv)
-    (_, lens, kh, vh, ssm, conv), toks_h = jax.lax.scan(
+    (_, lens, kh, vh, ssm, conv), (toks_h, loads) = jax.lax.scan(
         body, init, jnp.arange(horizon, dtype=jnp.int32))
 
     # the horizon's ONE pool scatter: buffer -> pages via the block table
@@ -612,4 +680,4 @@ def decode_loop_paged(params, cfg: ModelConfig, tokens,
         vh.astype(state.v.dtype))
     new_state = PagedDecodeState(k=k_pages, v=v_pages, block_table=table,
                                  lens=lens, ssm=ssm, conv=conv)
-    return jnp.moveaxis(toks_h, 0, 1), new_state
+    return _routed((jnp.moveaxis(toks_h, 0, 1), new_state), loads)

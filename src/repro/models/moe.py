@@ -1,18 +1,21 @@
-"""Top-k mixture-of-experts with group-local capacity dispatch.
+"""Top-k mixture of experts over the experts this program holds, drop-free.
 
-Design (TPU-native, GSPMD-friendly):
-  * tokens are grouped along the batch dimension (groups align with the data
-    sharding), capacity is per (group, expert) = ceil(topk * tokens_per_group
-    * capacity_factor / E);
-  * dispatch positions come from a one-hot cumulative sum *within the group*
-    (no global sort, no giant [N, E, C] dispatch einsum tensors);
-  * expert buffers [G, E, C, d] are scattered/gathered with per-group indices;
-    expert weights [E, d, ff] shard over `model` as expert-parallelism when
-    E % TP == 0 ("ep"), otherwise over the ff dim ("tp", expert-tensor-
-    parallel: granite's 40 experts on TP=16).
+The router scores every one of ``cfg.n_experts`` experts in float32:
+softmax, then top-k, with the top-k weights renormalised to sum to one only
+where the model does (``cfg.norm_topk_prob``: granite yes, OLMoE no).  The
+program holds the contiguous range ``[expert_offset, expert_offset +
+n_held)`` of them (every expert by default; one chip's share of an
+expert-parallel deployment otherwise) and computes only those experts' part
+of the layer's output, for exactly the (token, slot) pairs routed to them:
+the pairs are sorted by held expert and run through a grouped matmul
+(``kernels.expert_gmm`` on an unsharded TPU program, ``lax.ragged_dot``
+elsewhere), then weighted and summed back per token.  Nothing is dropped,
+whatever the load, and expert FLOPs follow the routed pairs.  What the
+experts held elsewhere would add is left out: no exchange between chips is
+modelled here.
 
-Overflowing tokens are dropped (standard capacity-based MoE); the router uses
-softmax-then-topk with renormalized combine weights (OLMoE-style).
+``moe_block`` also returns the layer's load: routed pairs per held expert,
+counting only the tokens marked valid (bucket padding is not traffic).
 """
 from __future__ import annotations
 
@@ -20,164 +23,135 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.expert_gmm import expert_gmm
 from repro.models.config import ModelConfig
-from repro.pshard import logical
+from repro.pshard import current_rules
+
+HI = jax.lax.Precision.HIGHEST
 
 
 def init_moe(key: jax.Array, cfg: ModelConfig, dtype) -> dict:
     ks = jax.random.split(key, 4)
-    d, ff, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    d, ff, E, H = cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.n_held
     s_in, s_out = 1.0 / np.sqrt(d), 1.0 / np.sqrt(ff)
     return {
         "router": (jax.random.normal(ks[0], (d, E)) * s_in).astype(jnp.float32),
-        "w_gate": (jax.random.normal(ks[1], (E, d, ff)) * s_in).astype(dtype),
-        "w_up": (jax.random.normal(ks[2], (E, d, ff)) * s_in).astype(dtype),
-        "w_down": (jax.random.normal(ks[3], (E, ff, d)) * s_out).astype(dtype),
+        "w_gate": (jax.random.normal(ks[1], (H, d, ff)) * s_in).astype(dtype),
+        "w_up": (jax.random.normal(ks[2], (H, d, ff)) * s_in).astype(dtype),
+        "w_down": (jax.random.normal(ks[3], (H, ff, d)) * s_out).astype(dtype),
     }
 
 
-def _pick_groups(n_tokens_per_seq: int, batch: int, n_experts: int,
-                 top_k: int) -> int:
-    """Groups divide the batch; keep tokens/group >= ~4*E/topk so the
-    per-expert capacity ceil() stays cheap, but cap group size for memory."""
-    target_tokens = max(4 * n_experts // max(top_k, 1), 64)
-    g = batch
-    while g > 1 and (batch // g) * n_tokens_per_seq < target_tokens:
-        # halve groups (g must divide batch; walk divisors downward)
-        for cand in range(g - 1, 0, -1):
-            if batch % cand == 0:
-                g = cand
-                break
-        else:
-            g = 1
-    return max(1, g)
-
-
-def moe_dense(x: jax.Array, p: dict, cfg: ModelConfig) -> jax.Array:
-    """Decode-path MoE: every expert on every token, gate-combined.
-
-    Exact (no capacity drops).  For single-token decode the step is
-    HBM-bound on the expert weights, which are read once regardless of the
-    routing — so the E/topk FLOPs overhead is hidden and this beats
-    per-token weight gathers for batch >= E/topk.
-    """
-    B, S, d = x.shape
-    E, K = cfg.n_experts, cfg.top_k
-    xt = x.reshape(B * S, d)
-    logits = xt.astype(jnp.float32) @ p["router"]
+def route(xt: jax.Array, p: dict, cfg: ModelConfig):
+    """xt [N, d] -> (combine weights [N, K] f32, experts [N, K] int32),
+    over all ``cfg.n_experts``; ``(probs [N, E])`` beside them."""
+    logits = jnp.dot(xt.astype(jnp.float32), p["router"], precision=HI)
     probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, topi = jax.lax.top_k(probs, K)
-    gate_vals = gate_vals / jnp.clip(gate_vals.sum(-1, keepdims=True), 1e-9)
-    gates = jnp.zeros_like(probs)
-    gates = jax.vmap(lambda g, i, v: g.at[i].set(v))(gates, topi, gate_vals)
-    gate = jnp.einsum("nd,edf->nef", xt, p["w_gate"])
-    up = jnp.einsum("nd,edf->nef", xt, p["w_up"])
-    h = jax.nn.silu(gate) * up
-    h = logical(h, None, "experts", "expert_ff")
-    y_all = jnp.einsum("nef,efd->ned", h, p["w_down"])
-    y = jnp.einsum("ned,ne->nd", y_all, gates.astype(x.dtype))
-    return y.reshape(B, S, d)
+    w, idx = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk_prob:
+        w = w / jnp.clip(w.sum(-1, keepdims=True), 1e-9)
+    return w, idx, probs
+
+
+def _ragged(lhs, rhs, sizes):
+    return jax.lax.ragged_dot(lhs, rhs, sizes,
+                              preferred_element_type=jnp.float32
+                              ).astype(lhs.dtype)
+
+
+@jax.custom_vjp
+def _gmm_kernel(lhs, rhs, sizes):
+    return expert_gmm(lhs, rhs, sizes)
+
+
+def _gmm_fwd(lhs, rhs, sizes):
+    return expert_gmm(lhs, rhs, sizes), (lhs, rhs, sizes)
+
+
+def _gmm_bwd(res, g):
+    # the kernel leaves rows past the groups undefined and ragged_dot zeros
+    # them; callers mask those rows, so their cotangent is zero either way
+    lhs, rhs, sizes = res
+    _, vjp = jax.vjp(lambda a, b: _ragged(a, b, sizes), lhs, rhs)
+    return (*vjp(g), None)
+
+
+_gmm_kernel.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def _gmm(lhs, rhs, sizes):
+    """Grouped matmul: the Pallas kernel in an unsharded program lowered
+    for TPU (GSPMD does not partition a pallas call), ``ragged_dot``
+    elsewhere."""
+    if current_rules() is not None:
+        return _ragged(lhs, rhs, sizes)
+    return jax.lax.platform_dependent(lhs, rhs, sizes, tpu=_gmm_kernel,
+                                      default=_ragged)
+
+
+def _per_expert(local, mask, H: int):
+    """Pairs with ``mask`` per held expert [H] (``local`` in [0, H))."""
+    group = jnp.where(mask, local, H).reshape(-1)
+    return jnp.zeros((H + 1,), jnp.int32).at[group].add(1)[:H]
 
 
 def moe_block(x: jax.Array, p: dict, cfg: ModelConfig,
-              capacity_factor: float | None = None) -> jax.Array:
-    """x: [B, S, d] -> [B, S, d]."""
+              valid: jax.Array | None = None):
+    """x [B, S, d] -> (the held experts' part [B, S, d], load [n_held]
+    int32: pairs of ``valid`` [B, S] tokens (default all) routed to each
+    held expert)."""
     B, S, d = x.shape
-    E, K = cfg.n_experts, cfg.top_k
-    # Small token counts (decode steps, CPU-scale smoke/serving): the dense
-    # path is exact and HBM-bound anyway.  Large scale uses capacity-based
-    # dispatch (drops bounded by the load-balancing loss during training).
-    if B * S <= 2048:
-        return moe_dense(x, p, cfg)
-    cf = capacity_factor if capacity_factor is not None else cfg.moe_capacity_factor
-    G = _pick_groups(S, B, E, K)
-    N = (B // G) * S                      # tokens per group
-    C = max(1, int(np.ceil(K * N * cf / E)))
-
-    xg = x.reshape(G, N, d)
-    logits = (xg.astype(jnp.float32) @ p["router"])          # [G, N, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, topi = jax.lax.top_k(probs, K)                # [G, N, K]
-    gate_vals = gate_vals / jnp.clip(gate_vals.sum(-1, keepdims=True), 1e-9)
-
-    # Position of each (token, slot) within its expert's capacity buffer:
-    # cumulative count of earlier assignments to the same expert in the group.
-    oh = jax.nn.one_hot(topi, E, dtype=jnp.int32)            # [G, N, K, E]
-    flat_oh = oh.reshape(G, N * K, E)
-    pos = jnp.cumsum(flat_oh, axis=1) - flat_oh              # exclusive cumsum
-    pos = (pos * flat_oh).sum(-1).reshape(G, N, K)           # [G, N, K]
-    keep = pos < C
-    slot = jnp.where(keep, topi * C + pos, E * C)            # overflow -> dump slot
-
-    # Scatter tokens into expert buffers [G, E*C (+1 dump), d].
-    def scatter_group(buf_idx, xs):
-        buf = jnp.zeros((E * C + 1, d), xs.dtype)
-        idx = buf_idx.reshape(N * K)
-        vals = jnp.repeat(xs, K, axis=0)
-        return buf.at[idx].add(vals)
-
-    buffers = jax.vmap(scatter_group)(slot, xg)[:, : E * C, :]
-    buffers = buffers.reshape(G, E, C, d)
-    buffers = logical(buffers, "moe_groups", "experts", None, None)
-
-    # Expert FFN (SwiGLU), batched over experts.
-    gate = jnp.einsum("gecd,edf->gecf", buffers, p["w_gate"])
-    up = jnp.einsum("gecd,edf->gecf", buffers, p["w_up"])
-    h = jax.nn.silu(gate) * up
-    h = logical(h, "moe_groups", "experts", None, "expert_ff")
-    out_buf = jnp.einsum("gecf,efd->gecd", h, p["w_down"])
-    out_buf = logical(out_buf, "moe_groups", "experts", None, None)
-    out_buf = out_buf.reshape(G, E * C, d)
-    out_buf = jnp.concatenate(
-        [out_buf, jnp.zeros((G, 1, d), out_buf.dtype)], axis=1)
-
-    # Gather back and combine with renormalized gates.
-    def gather_group(buf, idx):
-        return buf[idx]                                      # [N*K, d]
-
-    slots_out = jax.vmap(gather_group)(out_buf, slot.reshape(G, N * K))
-    slots_out = slots_out.reshape(G, N, K, d)
-    w = (gate_vals * keep).astype(x.dtype)[..., None]
-    yg = (slots_out * w).sum(axis=2)                         # [G, N, d]
-    return yg.reshape(B, S, d)
+    N, K, H = B * S, cfg.top_k, cfg.n_held
+    xt = x.reshape(N, d)
+    w, idx, _ = route(xt, p, cfg)
+    local = idx - cfg.expert_offset
+    held = (local >= 0) & (local < H)
+    # pairs sorted by held expert; those held elsewhere (H) go last
+    order = jnp.argsort(jnp.where(held, local, H).reshape(N * K), stable=True)
+    sizes = _per_expert(local, held, H)
+    rows = xt[order // K]                                 # [N*K, d] by expert
+    gate = _gmm(rows, p["w_gate"], sizes)
+    up = _gmm(rows, p["w_up"], sizes)
+    h = (jax.nn.silu(gate.astype(jnp.float32))
+         * up.astype(jnp.float32)).astype(x.dtype)
+    out = _gmm(h, p["w_down"], sizes)                     # [N*K, d]
+    inv = jnp.zeros_like(order).at[order].set(jnp.arange(N * K))
+    out = out[inv].reshape(N, K, d).astype(jnp.float32)
+    # rows of pairs held elsewhere are undefined: select, never multiply
+    y = jnp.where(held[..., None], out * w[..., None], 0.0).sum(axis=1)
+    load = (sizes if valid is None
+            else _per_expert(local, held & valid.reshape(N, 1), H))
+    return y.astype(x.dtype).reshape(B, S, d), load
 
 
 def load_balance_loss(x: jax.Array, p: dict, cfg: ModelConfig) -> jax.Array:
-    """Switch-style auxiliary loss: E * sum_e f_e * P_e.
+    """Switch-style auxiliary loss: E * sum_e f_e * P_e / K over all the
+    router's experts.
 
     f_e = fraction of tokens whose top-k set contains e; P_e = mean router
-    probability.  Keeps routing balanced so the capacity path's drop rate
-    stays negligible at scale.
+    probability.  Keeps routing balanced, so no expert's share of the
+    deployment's chips carries far more than its part of the load.
     """
-    B, S, d = x.shape
-    E, K = cfg.n_experts, cfg.top_k
-    logits = x.reshape(-1, d).astype(jnp.float32) @ p["router"]
-    probs = jax.nn.softmax(logits, axis=-1)
-    _, topi = jax.lax.top_k(probs, K)
-    chosen = jax.nn.one_hot(topi, E, dtype=jnp.float32).sum(1)  # [N, E]
-    f = chosen.mean(0)
-    P = probs.mean(0)
-    return E * jnp.sum(f * P) / K
+    E = cfg.n_experts
+    _, idx, probs = route(x.reshape(-1, x.shape[-1]), p, cfg)
+    chosen = jax.nn.one_hot(idx, E, dtype=jnp.float32).sum(1)  # [N, E]
+    return E * jnp.sum(chosen.mean(0) * probs.mean(0)) / cfg.top_k
 
 
 def moe_ref(x: jax.Array, p: dict, cfg: ModelConfig) -> jax.Array:
-    """Oracle: dense per-token expert evaluation (no capacity drops).
-
-    Used in tests; agreement holds whenever nothing overflows capacity.
-    """
+    """Oracle: every held expert on every token, weighted by the routing
+    (tiny shapes only)."""
     B, S, d = x.shape
-    E, K = cfg.n_experts, cfg.top_k
-    logits = x.astype(jnp.float32).reshape(-1, d) @ p["router"]
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, topi = jax.lax.top_k(probs, K)
-    gate_vals = gate_vals / jnp.clip(gate_vals.sum(-1, keepdims=True), 1e-9)
     xt = x.reshape(-1, d)
-    gates_full = jnp.zeros_like(probs)
-    gates_full = jax.vmap(lambda g, i, v: g.at[i].set(v))(gates_full, topi, gate_vals)
-    # every expert on every token (tiny shapes only)
-    gate = jnp.einsum("nd,edf->enf", xt, p["w_gate"])
-    up = jnp.einsum("nd,edf->enf", xt, p["w_up"])
-    h = jax.nn.silu(gate) * up
-    y_all = jnp.einsum("enf,efd->end", h, p["w_down"])
-    y = jnp.einsum("end,ne->nd", y_all, gates_full.astype(x.dtype))
-    return y.reshape(B, S, d)
+    w, idx, _ = route(xt, p, cfg)
+    local = idx - cfg.expert_offset
+    onehot = jax.nn.one_hot(local, cfg.n_held, dtype=jnp.float32)  # [N,K,H]
+    gates = jnp.einsum("nk,nkh->nh", w, onehot)
+    f32 = {k: p[k].astype(jnp.float32) for k in ("w_gate", "w_up", "w_down")}
+    xf = xt.astype(jnp.float32)
+    gate = jnp.einsum("nd,hdf->hnf", xf, f32["w_gate"], precision=HI)
+    up = jnp.einsum("nd,hdf->hnf", xf, f32["w_up"], precision=HI)
+    y_all = jnp.einsum("hnf,hfd->hnd", jax.nn.silu(gate) * up, f32["w_down"],
+                       precision=HI)
+    y = jnp.einsum("hnd,nh->nd", y_all, gates, precision=HI)
+    return y.astype(x.dtype).reshape(B, S, d)

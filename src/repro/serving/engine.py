@@ -257,12 +257,19 @@ class PendingDecode:
 
     Holds the device token block between ``step_async`` and
     ``finish_step`` so cross-replica dispatch can overlap device work; the
-    single ``np.asarray(tokens)`` in ``finish_step`` is the horizon's one
-    device→host transfer.
+    single read of ``tokens`` (with an expert model's ``route`` counts) in
+    ``finish_step`` is the horizon's one device→host transfer.
     """
     slots: list[int]
     tokens: jax.Array    # [B_bucket, horizon] device-resident token block
     horizon: int
+    route: tuple | None = None   # routing counts of an expert model
+
+
+def _route_last(cfg, out: tuple) -> tuple:
+    """A model program's outputs with its routing counts last: an expert
+    model's programs return them, any other's get None there."""
+    return out if cfg.is_moe else (*out, None)
 
 
 def _pow2_bucket(n: int, cap: int) -> int:
@@ -430,10 +437,11 @@ class ServingEngine:
 
         # named functions, so each program is ``jit_<name>`` in a trace
         def prefill(p, toks):
-            return models.prefill(p, cfg, tokens=toks)
+            return _route_last(cfg, models.prefill(p, cfg, tokens=toks))
 
         def prefill_chunk(p, t, k, v, tab, s, nv):
-            return models.prefill_chunk(p, cfg, t, k, v, tab, s, nv, trash)
+            return _route_last(cfg, models.prefill_chunk(
+                p, cfg, t, k, v, tab, s, nv, trash))
 
         def decode_dense(p, toks, cache):
             return models.decode_step(p, cfg, toks, cache)
@@ -447,6 +455,18 @@ class ServingEngine:
     def _span(self, name: str):
         """A host span of this replica (``serving.telemetry``'s names)."""
         return self.telemetry.span(name, replica=self.trace_id)
+
+    def _note_route(self, phase: str, tokens: int, route,
+                    steps: int = 1) -> None:
+        """Telemetry: one ``moe_route`` event per dispatch of an expert
+        model, from the routing counts read back with its tokens."""
+        if route is None or not self.telemetry.enabled:
+            return
+        per_expert, max_load = route
+        self.telemetry.emit("moe_route", replica=self.trace_id, phase=phase,
+                            tokens=int(tokens), steps=int(steps),
+                            routed_here=int(np.sum(per_expert)),
+                            max_load=int(max_load))
 
     def _rules_ctx(self):
         """Context installing the replica's logical-axis sharding rules.
@@ -493,17 +513,18 @@ class ServingEngine:
             conv = conv_full[:, slots] if conv_full is not None else None
             st = PagedDecodeState(k=k, v=v, block_table=table, lens=lens,
                                   ssm=ssm, conv=conv)
-            toks, st = decode_loop_paged(
+            # bucket padding rides the trash slot and is not traffic
+            toks, st, route = _route_last(cfg, decode_loop_paged(
                 params, cfg, tokens, st, key, step0, horizon,
                 attn_impl=impl, interpret=interp,
-                temperature=0.0 if greedy else 1.0)
+                temperature=0.0 if greedy else 1.0, valid=slots != trash))
             # padded rows advanced the trash slot's lens inside the loop;
             # pin it back to 0 so the trash row stays inert
             lens_full = lens_full.at[slots].set(st.lens).at[trash].set(0)
             if ssm_full is not None:
                 ssm_full = ssm_full.at[:, slots].set(st.ssm)
                 conv_full = conv_full.at[:, slots].set(st.conv)
-            return toks, st.k, st.v, lens_full, ssm_full, conv_full
+            return toks, st.k, st.v, lens_full, ssm_full, conv_full, route
 
         # donate the pools/state so XLA updates pages in place (no-op on CPU)
         donate = (1, 2, 4, 5, 6) if jax.default_backend() != "cpu" else ()
@@ -982,8 +1003,11 @@ class ServingEngine:
         for pl, group in by_len.items():
             toks = np.stack([r.prefill_tokens for r in group])
             with self._rules_ctx():
-                logits, cache = self._prefill(self.params, jnp.asarray(toks))
-            first = self._pick(logits)           # one sync per prefill group
+                logits, cache, route = self._prefill(self.params,
+                                                     jnp.asarray(toks))
+            # one sync per prefill group
+            first, route = self._pick(logits, route)
+            self._note_route("prefill", pl * len(group), route)
             self.prefill_tokens += pl * len(group)
             t_first = self.clock()
             for i, r in enumerate(group):
@@ -1026,14 +1050,15 @@ class ServingEngine:
             n_pages = _pow2_bucket(need, self.cache.max_blocks_per_seq)
             table = self.cache.block_table_dev[r.slot:r.slot + 1, :n_pages]
             with self._rules_ctx():
-                logits, k, v = self._chunk(self.params, jnp.asarray(buf),
-                                           self.cache.k, self.cache.v, table,
-                                           jnp.int32(start),
-                                           jnp.int32(n_valid))
+                logits, k, v, route = self._chunk(
+                    self.params, jnp.asarray(buf), self.cache.k,
+                    self.cache.v, table, jnp.int32(start),
+                    jnp.int32(n_valid))
             self.cache.k, self.cache.v = k, v
             self.prefill_tokens += n_valid      # cached tokens cost zero
             r.prefill_pos = len(toks)
-            first = self._pick(logits)
+            first, route = self._pick(logits, route)
+            self._note_route("prefill", n_valid, route)
             r.t_first = self.clock()
             fresh = not r.generated
             r.generated.append(int(first[0]))
@@ -1086,10 +1111,10 @@ class ServingEngine:
             n_pages = _pow2_bucket(need, self.cache.max_blocks_per_seq)
             table = self.cache.block_table_dev[slot:slot + 1, :n_pages]
             with self._rules_ctx():
-                logits, k, v = self._chunk(self.params, jnp.asarray(buf),
-                                           self.cache.k, self.cache.v, table,
-                                           jnp.int32(start),
-                                           jnp.int32(n_valid))
+                logits, k, v, route = self._chunk(
+                    self.params, jnp.asarray(buf), self.cache.k,
+                    self.cache.v, table, jnp.int32(start),
+                    jnp.int32(n_valid))
             self.cache.k, self.cache.v = k, v
             self.prefill_tokens += n_valid
             budget -= n_valid
@@ -1099,7 +1124,8 @@ class ServingEngine:
                                     replica=self.trace_id, tokens=n_valid,
                                     pos=r.prefill_pos)
             if r.prefill_pos >= len(toks_all):   # final chunk emits token 1
-                first = self._pick(logits)
+                first, route = self._pick(logits, route)
+                self._note_route("prefill", n_valid, route)
                 r.t_first = self.clock()
                 fresh = not r.generated
                 r.generated.append(int(first[0]))
@@ -1108,14 +1134,16 @@ class ServingEngine:
                     self._note_first_token(r, r.t_first)
                 self._publish(slot, r)
 
-    def _pick(self, logits: jax.Array) -> np.ndarray:
+    def _pick(self, logits: jax.Array, route=None):
+        """(the sampled ids, ``route``) on the host: an expert model's
+        routing counts come back in the same transfer as its ids."""
         if self.greedy:
             ids = sample(logits, self.cfg, self.key)
         else:
             self.key, sub = jax.random.split(self.key)
             ids = sample(logits, self.cfg, sub, temperature=1.0)
         with self._span("engine.wait_device"):
-            return np.asarray(ids)
+            return jax.device_get((ids, route))
 
     # -- decode paths ----------------------------------------------------------
 
@@ -1180,7 +1208,7 @@ class ServingEngine:
                 self.telemetry.emit("dispatch", replica=self.trace_id,
                                     n=B, h=horizon)
             with self._rules_ctx():
-                toks, k, v, lens_dev, ssm, conv = self._fused(
+                toks, k, v, lens_dev, ssm, conv, route = self._fused(
                     self.params, self.cache.k, self.cache.v,
                     self.cache.block_table_dev, self.cache.seq_lens_dev,
                     self.cache.ssm, self.cache.conv,
@@ -1189,13 +1217,15 @@ class ServingEngine:
             self.cache.k, self.cache.v = k, v
             self.cache.seq_lens_dev = lens_dev
             self.cache.ssm, self.cache.conv = ssm, conv
-            return PendingDecode(slots, toks, horizon)
+            return PendingDecode(slots, toks, horizon, route)
 
     def _finish_decode(self, pending: PendingDecode) -> None:
         """Sync a dispatched horizon: ONE [B, H] device→host transfer."""
         with self._span("engine.wait_device"):
-            toks = np.asarray(pending.tokens)
+            toks, route = jax.device_get((pending.tokens, pending.route))
         self.decode_syncs += 1
+        self._note_route("decode", len(pending.slots) * pending.horizon,
+                         route, pending.horizon)
         for i, s in enumerate(pending.slots):
             r = self.active[s]
             r.generated.extend(int(t) for t in toks[i, :pending.horizon])
@@ -1226,7 +1256,7 @@ class ServingEngine:
         dc = DecodeCache(k=k, v=v, ssm=ssm, conv=conv,
                          pos=jnp.asarray(lens, jnp.int32))
         logits, new_cache = self._decode(self.params, jnp.asarray(last), dc)
-        toks = self._pick(logits)
+        toks, _ = self._pick(logits)
         # persist the new KV token + SSM state back into the pool
         for s in slots:
             self.cache.extend(int(s))

@@ -56,6 +56,7 @@ the refcount lifecycle, and the eviction policy.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -552,8 +553,7 @@ class PagedKVCache:
         kb = jnp.swapaxes(kb, 2, 3)          # [L, n, Hkv, bs, D] native
         vb = jnp.swapaxes(vb, 2, 3)
         idx = jnp.asarray(self.seq_blocks[slot], jnp.int32)
-        self.k = self.k.at[:, idx].set(kb.astype(self.k.dtype))
-        self.v = self.v.at[:, idx].set(vb.astype(self.v.dtype))
+        self.k, self.v = _write_pages()(self.k, self.v, idx, kb, vb)
 
     def write_token(self, slots: np.ndarray, k_new: jax.Array,
                     v_new: jax.Array, positions: np.ndarray) -> None:
@@ -592,6 +592,21 @@ class PagedKVCache:
         k, v = k[..., :D], v[..., :D]   # drop kernel head_pad columns
         lens = jnp.asarray(self.seq_lens[slots])
         return k, v, lens
+
+
+def write_pages(k, v, idx, kb, vb):
+    """Pages ``idx`` of the pools k/v [L, P, Hkv, bs, D] set to kb/vb."""
+    return (k.at[:, idx].set(kb.astype(k.dtype)),
+            v.at[:, idx].set(vb.astype(v.dtype)))
+
+
+@functools.cache
+def _write_pages():
+    """``write_pages`` jitted with the pools donated off the CPU, so a
+    prefill's pages are written in place: an eager scatter would hold a
+    second copy of each pool while it runs."""
+    donate = (0, 1) if jax.default_backend() != "cpu" else ()
+    return jax.jit(write_pages, donate_argnums=donate)
 
 
 # --------------------------------------------------------------------------

@@ -35,6 +35,14 @@ prefill_chunk           tokens, pos
 first_token             ttft_s
 dispatch                n (batch size), h (horizon)
 sync                    n, tokens
+moe_route               phase ("prefill"|"decode"), tokens, steps,
+                        routed_here, max_load  [an expert model's
+                        dispatch, read back with its tokens: routed
+                        (token, expert) pairs to the experts held here
+                        summed over layers and steps, and the largest
+                        count of one expert in one layer and step; bucket
+                        padding is not counted; a chunked prefill reports
+                        the chunk that gives its first token]
 retire                  tokens                       [terminal]
 shed                    reason ("ttft"|"tpot"|"capacity")  [terminal]
 finish_log              tokens                       [terminal; cluster-side]

@@ -58,7 +58,7 @@ def test_decode_matches_forward(arch):
     B, S = 2, 48
     tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0,
                                 cfg.vocab_size)
-    lp, cache = prefill(params, cfg, tokens)
+    lp, cache = prefill(params, cfg, tokens)[:2]
     nxt = jnp.argmax(lp[:, :cfg.vocab_size], -1).astype(jnp.int32)
     big = init_cache(cfg, B, S + 4, jnp.float32)
     if cache.k is not None:

@@ -52,7 +52,7 @@ def test_engine_token_exact_vs_reference():
     news = [5, 3, 6]
 
     def ref_gen(prompt, n_new):
-        lp, cache = prefill(params, cfg, jnp.asarray(prompt)[None])
+        lp, cache = prefill(params, cfg, jnp.asarray(prompt)[None])[:2]
         big = init_cache(cfg, 1, len(prompt) + n_new + 2, jnp.float32)
         if cache.k is not None:
             big.k = big.k.at[:, :, :len(prompt)].set(cache.k)

@@ -10,6 +10,7 @@ fault plan (replica crash + a switch that fails mid-migration) every
 submitted request's event stream still ends in exactly one terminal event
 and every migration pairs a source with a destination replica.
 """
+import dataclasses
 import gc
 import json
 
@@ -533,3 +534,35 @@ def test_preempt_evict_resume_one_terminal(cfg_params):
     assert terminals.keys() == set(range(11))
     assert all(c == 1 for c in terminals.values()), \
         f"preempted-then-resumed requests duplicated terminals: {terminals}"
+
+
+def test_moe_route_one_event_per_dispatch_with_exact_counts():
+    """An expert model's decode tick emits one ``moe_route`` event whose
+    ``routed_here`` is tokens x top_k x held / E per layer, exactly: with
+    top_k = E every token routes to every expert, so the held share is
+    exact whatever the router says; the padded row of the batch bucket is
+    not counted."""
+    cfg = dataclasses.replace(get_smoke_config("olmoe-1b-7b"), n_experts=8,
+                              top_k=8, experts_held=4, expert_offset=2)
+    params = init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    tm = Telemetry(clock=FakeClock())
+    eng = ServingEngine(cfg, params, num_blocks=64, block_size=8,
+                        max_seqs=4, telemetry=tm)
+    rng = np.random.RandomState(5)
+    for i, n in enumerate((6, 7, 9)):
+        eng.submit(i, rng.randint(0, cfg.vocab_size, n).astype(np.int32), 4)
+    eng.step()                                  # prefill only
+    pre = [e for e in tm.tracer.events if e.kind == "moe_route"]
+    assert [e.data["phase"] for e in pre] == ["prefill"] * 3
+    assert sorted(e.data["tokens"] for e in pre) == [6, 7, 9]
+    L, K, held, E = cfg.n_layers, cfg.top_k, cfg.n_held, cfg.n_experts
+    for e in pre:
+        assert e.data["routed_here"] == e.data["tokens"] * K * held // E * L
+        assert e.data["max_load"] == e.data["tokens"]
+    eng.step()                                  # one decode tick, B=3 of 4
+    dec = [e for e in tm.tracer.events if e.kind == "moe_route"][len(pre):]
+    assert len(dec) == 1
+    d = dec[0].data
+    assert (d["phase"], d["tokens"]) == ("decode", 3)
+    assert d["routed_here"] == 3 * K * held // E * L
+    assert d["max_load"] == 3

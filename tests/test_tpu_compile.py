@@ -121,3 +121,37 @@ def test_gemma2_prefill_compiles(one_chip, hbm_bytes, no_persistent_cache):
         _sds(one_chip, (1, 2048), jnp.int32)).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < hbm_bytes
+
+
+def test_olmoe_share_decode_loop_fits_with_no_pool_copy(one_chip, hbm_bytes,
+                                                       no_persistent_cache):
+    """The olmoe-1b-7b.chat cell's fused decode loop: one chip's share of
+    EP=4 (16 of 64 experts), B=64 over a pool of 4,096 pages.  The grouped
+    expert kernel is in it, and the pools are written and read in place:
+    the program's own buffers stay far under one copy of them (4.3 GB)."""
+    import dataclasses
+
+    cfg = dataclasses.replace(get_config("olmoe-1b-7b"), experts_held=16)
+    B, P, n_pages = 64, 4096, 64
+    pool = _sds(one_chip, (cfg.n_layers, P + 1, cfg.n_kv_heads, PAGE,
+                           cfg.head_dim), jnp.bfloat16)
+
+    def loop(params, k, v, table, lens, toks, key):
+        st = M.PagedDecodeState(k=k, v=v, block_table=table, lens=lens,
+                                ssm=None, conv=None)
+        out, st, route = M.decode_loop_paged(
+            params, cfg, toks, st, key, jnp.int32(0), 1, attn_impl="kernel")
+        return out, st.k, st.v, route
+
+    compiled = jax.jit(loop, donate_argnums=(1, 2)).lower(
+        _param_shapes(cfg, one_chip), pool, pool,
+        _sds(one_chip, (B, n_pages), jnp.int32),
+        _sds(one_chip, (B,), jnp.int32), _sds(one_chip, (B,), jnp.int32),
+        _sds(one_chip, (2,), jnp.uint32)).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert any("expert_gmm" in line for line in calls)
+    assert any("paged_decode_attention" in line for line in calls)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2**30
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < hbm_bytes
