@@ -9,17 +9,21 @@ Variants:
     stored in kernel-native layout [L, P, Hkv, page, D]: the engine issues one
     pallas_call per layer with no per-(layer, step) transposes or reshapes.
 
-The paged kernel's grid is (B, Hkv, n_blocks).  A grid step takes all the
-query heads of one KV head's group as one [group, D] block and one block of
-``pages_per_block`` pages of that head, so each K/V page is read once per KV
-head and scores are a [group, block tokens] matmul.  Pages per block is the
-largest power of two with pages x page <= 256 tokens that the table width
-holds and divides (``_pages_per_block``); the engine's page buckets are
-powers of two.  The pools stay in HBM (``pl.ANY``); the block table rides in
-scalar-prefetch SMEM, and each block's pages are copied into a
-double-buffered VMEM block, one DMA per page, while the previous block
-computes: each live step starts the copies of the next live step in grid
-order.
+The paged kernel's grid is (B, Hkv // hb, n_blocks).  A grid step takes a
+block of ``hb`` KV heads of one sequence, with all the query heads of
+their groups as one [hb, group, D] block, over one block of
+``pages_per_block`` pages, so each K/V page is read once and scores are a
+[group, block tokens] matmul per KV head, batched over the ``hb`` heads.
+Pages per block is the largest power of two with pages x page <= 256
+tokens that the table width holds and divides (``_pages_per_block``); the
+engine's page buckets are powers of two.  ``hb`` is the largest divisor of
+Hkv whose double-buffered K and V blocks fit ``BLOCK_VMEM_BYTES``
+(``_heads_per_block``).  The pools stay in HBM (``pl.ANY``); the block
+table rides in scalar-prefetch SMEM, and each block's pages are copied
+into a double-buffered VMEM block, one DMA per page and pool for all
+``hb`` heads it holds (the pools are [.., P, Hkv, page, D], so a page's
+heads are one contiguous run), while the previous block computes: each
+live step starts the copies of the next live step in grid order.
 
 Per-sequence masking is a [start, len) window: ``lens`` masks the invalid
 tail, ``start`` (optional) masks the head for local/sliding-window layers.
@@ -129,6 +133,12 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, lens: jax.Array,
 # two V blocks stay small in VMEM.
 BLOCK_TOKENS = 256
 
+# VMEM a grid step's K and V blocks may take (2 pools x 2 slots x hb x
+# block tokens x D): 8 MiB holds all 16 of OLMoE's KV heads at 256 bf16
+# tokens (4 MiB) with room for the float32 V that P.V reads, well inside
+# v5e's 16 MiB of scoped VMEM.
+BLOCK_VMEM_BYTES = 8 * 2**20
+
 
 def _pages_per_block(page: int, max_pages: int) -> int:
     """Pages one grid step covers: the largest power of two whose pages hold
@@ -142,12 +152,23 @@ def _pages_per_block(page: int, max_pages: int) -> int:
     return ppb
 
 
+def _heads_per_block(n_kv: int, block_tokens: int, d: int,
+                     itemsize: int) -> int:
+    """KV heads one grid step covers: the largest divisor of ``n_kv`` whose
+    double-buffered K and V blocks fit ``BLOCK_VMEM_BYTES`` (one head at
+    least)."""
+    per_head = 2 * 2 * block_tokens * d * itemsize
+    return max((h for h in range(1, n_kv + 1)
+                if n_kv % h == 0 and h * per_head <= BLOCK_VMEM_BYTES),
+               default=1)
+
+
 def _paged_kernel(lens_ref, start_ref, table_ref, layer_ref, q_ref, k_hbm,
                   v_hbm, o_ref, k_buf, v_buf, k_sem, v_sem, flight, m_scr,
                   l_scr, acc_scr, *, scale, softcap, ppb, n_blocks):
     b, h, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     n_b, n_h = pl.num_programs(0), pl.num_programs(1)
-    page = k_hbm.shape[3]
+    hb, page = k_buf.shape[1], k_hbm.shape[3]
     bk = ppb * page
 
     def live_pages(bb):
@@ -160,7 +181,8 @@ def _paged_kernel(lens_ref, start_ref, table_ref, layer_ref, q_ref, k_hbm,
 
     def block_copies(bb, hh, jj, slot, wait):
         """Start (or wait for) the HBM->VMEM copies of block ``jj`` of
-        sequence ``bb``, KV head ``hh``: one per live page, into ``slot``."""
+        sequence ``bb``, KV heads [hh * hb, (hh + 1) * hb): one per live
+        page and pool, all ``hb`` heads of the page at once, into ``slot``."""
         first, last = live_pages(bb)
         for i in range(ppb):
             p = jj * ppb + i
@@ -170,8 +192,9 @@ def _paged_kernel(lens_ref, start_ref, table_ref, layer_ref, q_ref, k_hbm,
                 for pool, buf, sem in ((k_hbm, k_buf, k_sem),
                                        (v_hbm, v_buf, v_sem)):
                     cp = pltpu.make_async_copy(
-                        pool.at[layer_ref[0], table_ref[bb, p], hh],
-                        buf.at[slot, pl.ds(i * page, page)], sem.at[slot])
+                        pool.at[layer_ref[0], table_ref[bb, p],
+                                pl.ds(hh * hb, hb)],
+                        buf.at[slot, :, pl.ds(i * page, page)], sem.at[slot])
                     if wait:
                         cp.wait()
                     else:
@@ -201,8 +224,8 @@ def _paged_kernel(lens_ref, start_ref, table_ref, layer_ref, q_ref, k_hbm,
         def _start_own():
             block_copies(b, h, j, slot, wait=False)
 
-        # the next live step in grid order: the next block of this head, the
-        # first block of the next head, or that of the next sequence
+        # the next live step in grid order: the next block of these heads,
+        # the first block of the next heads, or that of the next sequence
         b1 = jnp.minimum(b + 1, n_b - 1)
         first1, last1 = live_pages(b1)
         more_blocks = (j + 1) * ppb <= last
@@ -222,36 +245,37 @@ def _paged_kernel(lens_ref, start_ref, table_ref, layer_ref, q_ref, k_hbm,
         flight[1] = has_next.astype(jnp.int32)
         block_copies(b, h, j, slot, wait=True)
 
-        q = q_ref[0, 0]                                  # [group, D]
-        k = k_buf[slot]                                  # [bk, D]
+        q = q_ref[0]                                     # [hb, group, D]
+        k = k_buf[slot]                                  # [hb, bk, D]
         dt = jnp.promote_types(q.dtype, k.dtype)
         s = jax.lax.dot_general(q.astype(dt), k.astype(dt),
-                                (((1,), (1,)), ((), ())),
+                                (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32) * scale
         if softcap > 0.0:
             s = softcap * jnp.tanh(s / softcap)
         length, start = lens_ref[b], start_ref[b]
-        pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s = jnp.where((pos >= start) & (pos < length), s, NEG_INF)
         # pages outside the window were not copied: their rows hold stale
         # VMEM, which must not reach P.V even at zero weight
-        row = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+        row = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk, 1), 1)
         v = jnp.where((row >= start) & (row < length),
                       v_buf[slot].astype(jnp.float32), 0.0)
 
-        m_prev = m_scr[...]
-        m_cur = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        m_prev = m_scr[...]                              # [hb, group, 1]
+        m_cur = jnp.maximum(m_prev, s.max(axis=2, keepdims=True))
         p = jnp.exp(s - m_cur)
         alpha = jnp.exp(m_prev - m_cur)
-        l_scr[...] = l_scr[...] * alpha + p.sum(axis=1, keepdims=True)
+        l_scr[...] = l_scr[...] * alpha + p.sum(axis=2, keepdims=True)
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            p, v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
         m_scr[...] = m_cur
 
     @pl.when(j == n_blocks - 1)
     def _finish():
-        o_ref[0, 0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-                       ).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 def _paged_call(q: jax.Array, kt: jax.Array, vt: jax.Array,
@@ -275,29 +299,32 @@ def _paged_call(q: jax.Array, kt: jax.Array, vt: jax.Array,
     ppb = _pages_per_block(page, max_pages)
     n_blocks = max_pages // ppb
     bk = ppb * page
+    # a step covers hb KV heads: one DMA per page and pool moves all of
+    # them, and the step's fixed cost is paid once for the hb heads
+    hb = _heads_per_block(Hkv, bk, D, kt.dtype.itemsize)
 
     kernel = functools.partial(_paged_kernel, scale=scale, softcap=softcap,
                                ppb=ppb, n_blocks=n_blocks)
-    # the group's query heads as one block whose last two dims are the
+    # hb KV heads' query heads as one block whose last two dims are the
     # array's, which Mosaic takes for any group size
-    q_spec = pl.BlockSpec((1, 1, group, D),
+    q_spec = pl.BlockSpec((1, hb, group, D),
                           lambda b, h, j, *prefetched: (b, h, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,             # lens, start, block_table, layer
-        grid=(B, Hkv, n_blocks),
+        grid=(B, Hkv // hb, n_blocks),
         in_specs=[q_spec,
                   pl.BlockSpec(memory_space=pl.ANY),   # pools stay in HBM
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((2, bk, D), kt.dtype),      # double-buffered blocks
-            pltpu.VMEM((2, bk, D), vt.dtype),
+            pltpu.VMEM((2, hb, bk, D), kt.dtype),  # double-buffered blocks
+            pltpu.VMEM((2, hb, bk, D), vt.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SMEM((2,), jnp.int32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, D), jnp.float32),
+            pltpu.VMEM((hb, group, 1), jnp.float32),
+            pltpu.VMEM((hb, group, 1), jnp.float32),
+            pltpu.VMEM((hb, group, D), jnp.float32),
         ],
     )
     out = pl.pallas_call(

@@ -16,7 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import get_smoke_config
+from repro.configs import ALL_ARCHS, get_config, get_smoke_config
+from repro.kernels import flash_decode as fd
 from repro.kernels import ops, ref
 from repro.models import init_params
 from repro.serving.engine import ServingEngine
@@ -75,7 +76,9 @@ def test_paged_start_window_masks_head():
 # step covers min(256 tokens, table) of pages, so widths 1 and 2 give blocks
 # smaller than the rule's, 32+ give the rule's; lengths of 1, ending
 # mid-page and mid-block, a full table, and empty (padded) slots between
-# live ones, across which the next block's copies are not started early
+# live ones, across which the next block's copies are not started early.
+# g1_h16 is OLMoE's geometry (MHA, 16 KV heads of 128), one grid step for
+# all 16 heads unless BLOCK_CASE_OPTS shrinks the VMEM budget
 BLOCK_CASES = {
     "g2_d256_softcap_start": (2, 2, 256, 16, 64, [700, 300, 1024, 1],
                               [520, 0, 600, 0], 50.0, jnp.float32),
@@ -91,12 +94,32 @@ BLOCK_CASES = {
                       jnp.float32),
     "empty_slots": (2, 4, 128, 16, 32, [0, 40, 0, 0, 300, 0], None, 0.0,
                     jnp.float32),
+    "g1_h16_d128": (16, 1, 128, 16, 64, [300, 0, 1024, 517, 1], None, 0.0,
+                    jnp.float32),
+    "g1_h16_d128_bf16": (16, 1, 128, 16, 64, [700, 1024, 0, 33, 256], None,
+                         0.0, jnp.bfloat16),
+    "g1_h16_d128_hb4": (16, 1, 128, 16, 64, [520, 0, 1024, 17], None, 0.0,
+                        jnp.float32),
+    "g1_h16_d128_layer": (16, 1, 128, 16, 64, [1024, 0, 381, 256], None,
+                          0.0, jnp.float32),
+}
+# budget: the VMEM budget the case runs under, so that a step covers fewer
+# KV heads than the array has (hb, checked); layers: the case goes through
+# the stacked-pool entry, reading layer 2 of 3 in place
+BLOCK_CASE_OPTS = {
+    "g1_h16_d128_hb4": {"budget": 2 * 2**20, "hb": 4},
+    "g1_h16_d128_layer": {"layers": 3},
 }
 
 
 @pytest.mark.parametrize("case", list(BLOCK_CASES), ids=list(BLOCK_CASES))
-def test_paged_blocks_vs_ref(case):
+def test_paged_blocks_vs_ref(case, monkeypatch):
     Hkv, group, D, page, maxp, lens, start, softcap, dtype = BLOCK_CASES[case]
+    opts = BLOCK_CASE_OPTS.get(case, {})
+    if "budget" in opts:
+        monkeypatch.setattr(fd, "BLOCK_VMEM_BYTES", opts["budget"])
+        bk = fd._pages_per_block(page, maxp) * page
+        assert fd._heads_per_block(Hkv, bk, D, 4) == opts["hb"]
     B = len(lens)
     pages = B * maxp + 3
     q = arr(B, Hkv * group, D).astype(dtype)
@@ -107,8 +130,17 @@ def test_paged_blocks_vs_ref(case):
     lens = jnp.asarray(lens, jnp.int32)
     if start is not None:
         start = jnp.asarray(start, jnp.int32)
-    o = ops.flash_decode_paged(q, kp, vp, tbl, lens, start=start,
-                               softcap=softcap, interpret=True)
+    if "layers" in opts:
+        kL, vL = (jnp.stack([jnp.swapaxes(x, 1, 2) * (1.0 + 0.5 * i)
+                             for i in range(opts["layers"])])
+                  for x in (kp, vp))
+        o = fd.flash_decode_paged_native(q, kL, vL, tbl, lens, start=start,
+                                         softcap=softcap, interpret=True,
+                                         layer=jnp.int32(2))
+        kp, vp = kp * 2.0, vp * 2.0
+    else:
+        o = ops.flash_decode_paged(q, kp, vp, tbl, lens, start=start,
+                                   softcap=softcap, interpret=True)
     r = ref.flash_decode_paged_ref(q, kp, vp, tbl, lens, start=start,
                                    softcap=softcap)
     live = np.asarray(lens) > 0          # an empty window has no softmax
@@ -116,6 +148,27 @@ def test_paged_blocks_vs_ref(case):
     np.testing.assert_allclose(np.asarray(o, np.float32)[live],
                                np.asarray(r, np.float32)[live], atol=atol)
     assert not np.any(np.isnan(np.asarray(o, np.float32)))
+
+
+@pytest.mark.parametrize("arch", [a for a in ALL_ARCHS
+                                  if get_config(a).n_kv_heads])
+def test_heads_per_block_rule(arch):
+    """The KV heads one grid step covers, for every configuration at the
+    engine's block (256 tokens) of bf16 pools padded to 128 lanes: a
+    divisor of Hkv whose blocks fit the budget, and the largest such."""
+    cfg = get_config(arch)
+    n_kv, d = cfg.n_kv_heads, -(-cfg.head_dim // 128) * 128
+    hb = fd._heads_per_block(n_kv, fd.BLOCK_TOKENS, d, 2)
+
+    def fits(h):
+        return 2 * 2 * h * fd.BLOCK_TOKENS * d * 2 <= fd.BLOCK_VMEM_BYTES
+
+    assert n_kv % hb == 0 and fits(hb)
+    assert not any(n_kv % h == 0 and fits(h) for h in range(hb + 1, n_kv + 1))
+    pinned = {"olmoe-1b-7b": 16, "starcoder2-3b": 2, "yi-9b": 4,
+              "gemma2-2b": 4, "hymba-1.5b": 5}
+    if arch in pinned:
+        assert hb == pinned[arch]
 
 
 def test_paged_batch_entry_matches_per_layer():

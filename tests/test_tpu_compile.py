@@ -65,24 +65,36 @@ def _param_shapes(cfg, sharding):
     return jax.tree.map(lambda x: _sds(sharding, x.shape, x.dtype), shapes)
 
 
-@pytest.mark.parametrize("arch,B", [
-    pytest.param("gemma2-2b", 8, id="gemma2-2b"),
-    pytest.param("starcoder2-3b", 8, id="starcoder2-3b"),
-    pytest.param("starcoder2-3b", 16, id="starcoder2-3b-codegen"),
-    pytest.param("yi-9b", 8, id="yi-9b"),
+@pytest.mark.parametrize("arch,B,n_pages,layers", [
+    pytest.param("gemma2-2b", 8, 128, None, id="gemma2-2b"),
+    pytest.param("starcoder2-3b", 8, 128, None, id="starcoder2-3b"),
+    pytest.param("starcoder2-3b", 16, 128, None, id="starcoder2-3b-codegen"),
+    pytest.param("yi-9b", 8, 128, None, id="yi-9b"),
+    pytest.param("olmoe-1b-7b", 64, 64, 16, id="olmoe-1b-7b-chat"),
 ])
-def test_paged_decode_kernel_compiles(arch, B, one_chip, no_persistent_cache):
+def test_paged_decode_kernel_compiles(arch, B, n_pages, layers, one_chip,
+                                      no_persistent_cache):
     """One kernel per geometry: group 2 / D 256, group 12 / D 128 (also at
-    the codegen cell's B=16 over a 128-page table), group 8 / D 128.  The
+    the codegen cell's B=16 over a 128-page table), group 8 / D 128, and
+    group 1 over 16 KV heads in one grid step at the chat cell's B=64 over
+    a 64-page table, reading layer ``layer`` of 16 stacked pools.  The
     benchmark's kernel reading keys on the custom call's name."""
     cfg = get_config(arch)
-    P, n_pages = 2048, 128
+    P = 2048
     Hq, Hkv, D = cfg.n_q_heads, cfg.n_kv_heads, cfg.head_dim
-    pool = _sds(one_chip, (P, Hkv, PAGE, D), jnp.bfloat16)
-    compiled = jax.jit(fd.flash_decode_paged_native).lower(
-        _sds(one_chip, (B, Hq, D), jnp.bfloat16), pool, pool,
-        _sds(one_chip, (B, n_pages), jnp.int32),
-        _sds(one_chip, (B,), jnp.int32)).compile()
+    stack = () if layers is None else (layers,)
+    pool = _sds(one_chip, (*stack, P, Hkv, PAGE, D), jnp.bfloat16)
+    args = [_sds(one_chip, (B, Hq, D), jnp.bfloat16), pool, pool,
+            _sds(one_chip, (B, n_pages), jnp.int32),
+            _sds(one_chip, (B,), jnp.int32)]
+    if layers is None:
+        fn = fd.flash_decode_paged_native
+    else:
+        def fn(q, k, v, table, lens, layer):
+            return fd.flash_decode_paged_native(q, k, v, table, lens,
+                                                layer=layer)
+        args.append(_sds(one_chip, (), jnp.int32))
+    compiled = jax.jit(fn).lower(*args).compile()
     assert any("paged_decode_attention" in line
                for line in compiled.as_text().splitlines()
                if 'custom_call_target="tpu_custom_call"' in line)
